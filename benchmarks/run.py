@@ -50,6 +50,10 @@ def main() -> None:
         help="per-benchmark wall-clock budget; exceeding it fails that bench",
     )
     args = ap.parse_args()
+
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import (

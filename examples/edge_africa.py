@@ -11,6 +11,7 @@ from repro.chaos import ChaosSchedule
 from repro.core import EdgeClient, FederatedServer, ServerConfig, fedavg, mnist_cnn_task
 from repro.data import make_federated_mnist, synthetic_mnist
 from repro.transport import DEFAULT, PROFILES, TUNED_EDGE, classify
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(link, tcp, rounds=5):
@@ -29,6 +30,7 @@ def run(link, tcp, rounds=5):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print(f"{'profile':14s} {'region':11s} {'default_time':>13s} {'tuned_time':>11s} {'acc':>6s}")
     for name in ("global_avg", "europe", "n_america", "asia", "africa", "africa_urban", "africa_rural"):
         link = PROFILES[name]

@@ -11,6 +11,7 @@ from repro.chaos import ChaosSchedule, client_failure_schedule, netem
 from repro.core import EdgeClient, FederatedServer, ServerConfig, fedavg, mnist_cnn_task
 from repro.data import make_federated_mnist, synthetic_mnist
 from repro.transport import DEFAULT, LAB, TUNED_EDGE
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(tcp, label):
@@ -42,6 +43,7 @@ def run(tcp, label):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("== Surviving the Edge: quickstart ==")
     d = run(DEFAULT, "default")
     t = run(TUNED_EDGE, "tuned")

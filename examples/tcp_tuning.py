@@ -14,6 +14,7 @@ import math
 from repro.transport import DEFAULT, LAB, TcpParams, client_round, effective_rtt
 from repro.tuning import AdaptiveTuner, tune_three_params
 from repro.tuning.grid import SWEEPS, best_per_latency, sweep_parameter
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -58,4 +59,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import tempfile
 
 from repro.configs import get_reduced
 from repro.launch.train import train
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def lm_100m_cfg():
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -216,8 +216,13 @@ def _int8_rows(x2):
     round-half-up math runs as one fused XLA pass (interpret-mode Pallas is
     several times slower than XLA, so CI never pays the interpreter on the
     server hot path — tests assert kernel == reference separately).
+
+    The scale multiplies by 1/127 rather than dividing by 127: XLA rewrites
+    a division by a constant into that multiplication inside a jit but not
+    in an eager op, so only the product is the same in the plane program
+    and the eager sequential path.
     """
-    scale = jnp.maximum(jnp.max(jnp.abs(x2), axis=-1), 1e-12) / 127.0
+    scale = jnp.maximum(jnp.max(jnp.abs(x2), axis=-1), 1e-12) * (1.0 / 127.0)
     if kernel_ops.default_interpret():
         q = kernel_ref.quantize_rows_ref(x2, scale)
     else:

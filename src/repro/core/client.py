@@ -9,14 +9,15 @@ multiplier over measured step cost, (3) a transport connection state
 configs implement the same interface, so every benchmark can swap payloads.
 
 The cohort/scenario hot path is the *plane* formulation: local SGD for any
-set of (anchor params, client, batch plan) rows runs as ONE stacked tensor
-program with a leading row axis. Rows are independent by construction —
-every cross-row operation is batch-mapped, never reduced — so a row's
-result is bitwise identical no matter how rows are grouped into dispatches.
-The batched cohort engine (one scenario, rows = cohort) and the grid engine
-(rows = union of cohorts across sweep points, see ``repro.core.grid``)
-share this runner, which is what makes grid sweeps exactly reproduce
-per-point runs.
+set of (anchor params, client, batch plan) rows runs as stacked tensor
+programs with a leading row axis, ``PLANE_ROWS`` rows per dispatch. Rows
+are independent by construction — every cross-row operation is
+batch-mapped, never reduced — and every dispatch has the same width, so a
+row's result is bitwise identical no matter how rows are grouped into
+planes. The batched cohort engine (one scenario, rows = cohort) and the
+grid engine (rows = union of cohorts across sweep points, see
+``repro.core.grid``) share this runner, which is what makes grid sweeps
+exactly reproduce per-point runs.
 """
 
 from __future__ import annotations
@@ -68,47 +69,39 @@ class LocalTask:
     # fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None) ->
     #     (plane_delta [Rb,...], n_examples [R], metrics [R]) where
     # rows is a list of R (client, plan) pairs, mus is a list of R prox
-    # coefficients, and Rb is R padded up to a bucket width (callers
-    # slice/gather the rows they own). ``anchors`` is a list of UNIQUE
-    # per-row params pytrees and ``anchor_idx`` maps each row to its
-    # anchor — the plane stacks O(unique anchors) and gathers rows inside
-    # the jit, so few-anchor planes (most grid rounds reference 1-3
-    # distinct anchor trees) stop materializing O(rows x params) at the
-    # dispatch boundary. ``anchor_idx=None`` means anchors is per-row
-    # (len R, identity mapping). One fused dispatch per call (chunked past
-    # _UNROLL_LIMIT steps).
+    # coefficients, and Rb is R padded up to whole PLANE_ROWS blocks
+    # (callers slice/gather the rows they own). ``anchors`` is a list of
+    # UNIQUE per-row params pytrees and ``anchor_idx`` maps each row to its
+    # anchor — each block stacks only the anchors its rows reference and
+    # gathers rows inside the jit. ``anchor_idx=None`` means anchors is
+    # per-row (len R, identity mapping). One fused dispatch per block
+    # (chunked past _UNROLL_LIMIT steps).
     fit_rows: Optional[Callable] = None
 
     def plane_dispatch_widths(self) -> List[int]:
-        """Padded row widths of every plane dispatch so far (test/bench
-        introspection for compile-cache bucketing)."""
+        """Row widths of every plane dispatch so far (test/bench
+        introspection: each is PLANE_ROWS)."""
         runner = getattr(self.fit_rows, "runner", None)
         return list(runner.dispatch_widths) if runner is not None else []
-
-    def plane_anchor_widths(self) -> List[int]:
-        """Padded UNIQUE-anchor widths of every plane dispatch so far —
-        the stacked-anchor transfer is O(width x params), so these sitting
-        far below the row widths is the gather formulation's win."""
-        runner = getattr(self.fit_rows, "runner", None)
-        return list(runner.anchor_widths) if runner is not None else []
 
 
 _UNROLL_LIMIT = 16  # local steps fused into one program before chunking
 _CHUNK_STEPS = 8  # fused block size for long local epochs (compile-bounded)
 
-# Row-bucket ladder: plane dispatches pad their row count up to the next
-# bucket so chaos-variable cohort sizes compile O(buckets) programs instead
-# of O(distinct sizes). Padding rows are discarded; row independence means
-# they cannot perturb real rows.
-_ROW_BUCKETS = (1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64, 96, 128)
+# Rows per plane dispatch. A plane runs as blocks of exactly this many rows
+# (the last block padded; padding rows are computed and discarded), so one
+# program per (steps, prox) serves every cohort and grid width. The fixed
+# width is also what keeps a row's result independent of its plane: on a
+# TPU v5e, XLA's lowering of the fit program changes the summation order of
+# the conv weight-gradient einsum and of the bias-gradient sums with the
+# row count, while at a fixed width a row's result depends neither on its
+# position nor on its neighbours.
+PLANE_ROWS = 8
 
 
 def bucket_rows(n: int) -> int:
-    """Smallest bucket width >= n (multiples of 64 past the ladder)."""
-    for b in _ROW_BUCKETS:
-        if n <= b:
-            return b
-    return -(-n // 64) * 64
+    """Rows a plane of ``n`` rows dispatches: n padded to whole blocks."""
+    return -(-n // PLANE_ROWS) * PLANE_ROWS
 
 
 def _plane_sgd_runner(cohort_loss_fn, lr: float):
@@ -209,7 +202,6 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
         leaves = jax.tree.leaves(batches)
         r, steps = leaves[0].shape[:2]
         run_rows.dispatch_widths.append(int(r))
-        run_rows.anchor_widths.append(int(jax.tree.leaves(uanchor)[0].shape[0]))
         if steps <= _UNROLL_LIMIT:
             return fit_fused(uanchor, aidx, batches, mu, use_prox, steps)
         stacked, opt_state, anchor = init_state(uanchor, aidx)
@@ -225,7 +217,6 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
         return finalize(stacked, anchor), metrics
 
     run_rows.dispatch_widths = []
-    run_rows.anchor_widths = []
     return run_rows
 
 
@@ -234,34 +225,57 @@ def _unstack_metrics(stacked: Dict[str, Any], n: int) -> List[Dict[str, float]]:
     return [{k: float(v[i]) for k, v in host.items()} for i in range(n)]
 
 
-def _pad_rows(rows: Sequence[Any], mus: Sequence[float], aidx: Sequence[int]):
-    """Pad a row list up to its bucket width by repeating row 0 (results
-    for padding rows are computed and discarded; row independence keeps
-    them from touching real rows)."""
-    r = len(rows)
-    rb = bucket_rows(r)
-    pad = rb - r
+def _row_blocks(anchors: Sequence[Any], anchor_idx, rows: Sequence[Any],
+                mus: Sequence[float]):
+    """Split a plane into PLANE_ROWS-row blocks.
+
+    Yields (anchor table, anchor_idx, rows, mus) per block, each padded to
+    PLANE_ROWS by repeating its first entry. A block's table holds only the
+    anchors its rows reference, in first-use order; padding anchors are
+    never gathered and padding rows' results are discarded.
+    ``anchor_idx=None`` means anchors is per-row (identity mapping).
+    """
+    aidx = list(range(len(rows)) if anchor_idx is None else anchor_idx)
+    for s in range(0, len(rows), PLANE_ROWS):
+        local: Dict[int, int] = {}
+        for a in aidx[s : s + PLANE_ROWS]:
+            local.setdefault(a, len(local))
+        table = [anchors[a] for a in local]
+        idx = [local[a] for a in aidx[s : s + PLANE_ROWS]]
+        blk_rows = list(rows[s : s + PLANE_ROWS])
+        blk_mus = [float(m) for m in mus[s : s + PLANE_ROWS]]
+        pad = PLANE_ROWS - len(blk_rows)
+        yield (
+            table + [table[0]] * (PLANE_ROWS - len(table)),
+            idx + [idx[0]] * pad,
+            blk_rows + [blk_rows[0]] * pad,
+            blk_mus + [blk_mus[0]] * pad,
+        )
+
+
+def _fit_blocks(runner, batches_for, anchors, rows, mus, use_prox, anchor_idx):
+    """Run a plane block by block through ``runner``; ``batches_for(rows)``
+    builds one block's step batches. Returns (plane [bucket_rows(R), ...],
+    per-row last-step metrics [R])."""
+    planes, lasts = [], []
+    for table, idx, blk_rows, blk_mus in _row_blocks(anchors, anchor_idx, rows, mus):
+        plane, last = runner(
+            tree_stack(table),
+            jnp.asarray(np.asarray(idx, np.int32)),
+            batches_for(blk_rows),
+            jnp.asarray(np.asarray(blk_mus, np.float32)),
+            use_prox,
+        )
+        planes.append(plane)
+        lasts.append(last)
+
+    def cat(*ls):
+        return ls[0] if len(ls) == 1 else jnp.concatenate(ls)
+
     return (
-        list(rows) + [rows[0]] * pad,
-        list(mus) + [float(mus[0])] * pad,
-        list(aidx) + [int(aidx[0])] * pad,
+        jax.tree.map(cat, *planes),
+        _unstack_metrics(jax.tree.map(cat, *lasts), len(rows)),
     )
-
-
-def _pad_anchors(anchors: Sequence[Any]):
-    """Pad the unique-anchor list up to its bucket width (anchor 0
-    repeated) so anchor counts ride the same compile-cache ladder as row
-    counts; padding anchors are never gathered by real rows."""
-    u = len(anchors)
-    return list(anchors) + [anchors[0]] * (bucket_rows(u) - u)
-
-
-def _anchor_args(anchors: Sequence[Any], anchor_idx, r: int):
-    """Normalize (anchors, anchor_idx) into the runner's gather form:
-    anchor_idx=None means anchors is per-row (identity mapping)."""
-    if anchor_idx is None:
-        anchor_idx = list(range(r))
-    return _pad_anchors(anchors), list(anchor_idx)
 
 
 def _sgd_local_fit(loss_fn, lr: float, batch_size: int):
@@ -316,26 +330,17 @@ def _sgd_plane_fns(cohort_loss_fn, lr: float, batch_size: int):
     def plan_digest(client: "EdgeClient", plan: np.ndarray):
         return (id(client.dataset), plan.tobytes())
 
-    def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
-        r = len(rows)
-        anchors_p, aidx = _anchor_args(anchors, anchor_idx, r)
-        rows_p, mus_p, aidx_p = _pad_rows(rows, mus, aidx)
-        batches = {
-            "images": jnp.asarray(
-                np.stack([c.dataset.images[p] for c, p in rows_p])
-            ),
-            "labels": jnp.asarray(
-                np.stack([c.dataset.labels[p] for c, p in rows_p])
-            ),
+    def batches_for(rows):
+        return {
+            "images": jnp.asarray(np.stack([c.dataset.images[p] for c, p in rows])),
+            "labels": jnp.asarray(np.stack([c.dataset.labels[p] for c, p in rows])),
         }
-        plane, last = runner(
-            tree_stack(anchors_p),
-            jnp.asarray(np.asarray(aidx_p, np.int32)),
-            batches,
-            jnp.asarray(np.asarray(mus_p, np.float32)),
-            use_prox,
+
+    def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
+        plane, metrics = _fit_blocks(
+            runner, batches_for, anchors, rows, mus, use_prox, anchor_idx
         )
-        return plane, [steps * batch_size] * r, _unstack_metrics(last, r)
+        return plane, [steps * batch_size] * len(rows), metrics
 
     fit_rows.runner = runner
     return plan_fit, plan_digest, fit_rows
@@ -457,12 +462,9 @@ def lm_task(cfg, lr: float = 1e-3, batch_size: int = 4, seq: int = 64) -> LocalT
     def plan_digest(client, plan):
         return (client.client_id, tuple(plan))
 
-    def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
-        r = len(rows)
-        anchors_p, aidx = _anchor_args(anchors, anchor_idx, r)
-        rows_p, mus_p, aidx_p = _pad_rows(rows, mus, aidx)
+    def batches_for(rows):
         per_row = []
-        for c, plan in rows_p:
+        for c, plan in rows:
             bs = [
                 token_batch_for(
                     cfg, batch=batch_size, seq=seq, seed=s, client_id=c.client_id
@@ -470,18 +472,16 @@ def lm_task(cfg, lr: float = 1e-3, batch_size: int = 4, seq: int = 64) -> LocalT
                 for s in plan
             ]
             per_row.append({k: np.stack([b[k] for b in bs]) for k in bs[0]})
-        batches = {
+        return {
             k: jnp.asarray(np.stack([pr[k] for pr in per_row]))
             for k in per_row[0]
         }
-        plane, last = runner(
-            tree_stack(anchors_p),
-            jnp.asarray(np.asarray(aidx_p, np.int32)),
-            batches,
-            jnp.asarray(np.asarray(mus_p, np.float32)),
-            use_prox,
+
+    def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
+        plane, metrics = _fit_blocks(
+            runner, batches_for, anchors, rows, mus, use_prox, anchor_idx
         )
-        return plane, [steps * batch_size] * r, _unstack_metrics(last, r)
+        return plane, [steps * batch_size] * len(rows), metrics
 
     fit_rows.runner = runner
 

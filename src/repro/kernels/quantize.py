@@ -23,6 +23,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# Rows per block once the row axis no longer fits one block. Mosaic tiles
+# the last two block dims by (8, 128) for f32 and (32, 128) for int8 unless
+# a dim spans its whole array, so a row block is either all R rows or a
+# multiple of 32.
+_ROW_BLOCK = 32
+
 
 def _quant_kernel(x_ref, u_ref, s_ref, q_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -60,10 +66,24 @@ def dequantize_flat(q, scale):
     return q.astype(jnp.float32) * scale
 
 
+def _row_blocks(R: int, N: int, tile: int):
+    """(block shape, grid) for an elementwise kernel over rows [R, N].
+
+    A block spans every row up to ``_ROW_BLOCK`` rows and the whole row up
+    to ``tile`` columns; past that it is ``_ROW_BLOCK`` rows or ``tile``
+    columns. Edge blocks that overhang R or N are partial: Pallas drops
+    their out-of-range writes, and the op is elementwise, so no padding
+    copy is made.
+    """
+    if tile % 128:
+        raise ValueError(f"tile must be a multiple of 128, got {tile}")
+    br = R if R <= _ROW_BLOCK else _ROW_BLOCK
+    bn = N if N <= tile else tile
+    return (br, bn), (pl.cdiv(R, br), pl.cdiv(N, bn))
+
+
 def _quant_rows_kernel(x_ref, s_ref, q_ref):
-    x = x_ref[...].astype(jnp.float32)
-    scale = s_ref[0, 0]
-    y = x / scale
+    y = x_ref[...].astype(jnp.float32) / s_ref[...]  # [br, bn] / [br, 1]
     q = jnp.floor(y + 0.5)  # deterministic round-half-up (parity contract)
     q_ref[...] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
 
@@ -71,27 +91,23 @@ def _quant_rows_kernel(x_ref, s_ref, q_ref):
 def quantize_rows_flat(x, scales, *, tile: int = 2048, interpret: bool = False):
     """x [R, N] f32, scales [R] (per-row quantum) -> int8 [R, N].
 
-    One grid cell per (row, tile); each row reads its own scale through a
-    (1, 1) block. Deterministic rounding: the plane compressors need the
-    kernel output bitwise equal to the sequential per-client reference.
+    Each block reads its rows' scales through an [rows, 1] block.
+    Deterministic rounding: the plane compressors need the kernel output
+    bitwise equal to the sequential per-client reference.
     """
     R, N = x.shape
-    pad = (-N) % tile
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    Np = x.shape[1]
-    q = pl.pallas_call(
+    block, grid = _row_blocks(R, N, tile)
+    return pl.pallas_call(
         _quant_rows_kernel,
-        grid=(R, Np // tile),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile), lambda r, i: (r, i)),
-            pl.BlockSpec((1, 1), lambda r, i: (r, 0)),
+            pl.BlockSpec(block, lambda r, i: (r, i)),
+            pl.BlockSpec((block[0], 1), lambda r, i: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile), lambda r, i: (r, i)),
-        out_shape=jax.ShapeDtypeStruct((R, Np), jnp.int8),
+        out_specs=pl.BlockSpec(block, lambda r, i: (r, i)),
+        out_shape=jax.ShapeDtypeStruct((R, N), jnp.int8),
         interpret=interpret,
     )(x, scales.reshape(R, 1))
-    return q[:, :N]
 
 
 def dequantize_rows(q, scales):
@@ -105,16 +121,12 @@ def _bf16_rows_kernel(x_ref, o_ref):
 def downcast_bf16_rows_flat(x, *, tile: int = 2048, interpret: bool = False):
     """x [R, N] f32 -> bf16 [R, N] (round-to-nearest-even downcast)."""
     R, N = x.shape
-    pad = (-N) % tile
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    Np = x.shape[1]
-    out = pl.pallas_call(
+    block, grid = _row_blocks(R, N, tile)
+    return pl.pallas_call(
         _bf16_rows_kernel,
-        grid=(R, Np // tile),
-        in_specs=[pl.BlockSpec((1, tile), lambda r, i: (r, i))],
-        out_specs=pl.BlockSpec((1, tile), lambda r, i: (r, i)),
-        out_shape=jax.ShapeDtypeStruct((R, Np), jnp.bfloat16),
+        grid=grid,
+        in_specs=[pl.BlockSpec(block, lambda r, i: (r, i))],
+        out_specs=pl.BlockSpec(block, lambda r, i: (r, i)),
+        out_shape=jax.ShapeDtypeStruct((R, N), jnp.bfloat16),
         interpret=interpret,
     )(x)
-    return out[:, :N]

@@ -30,8 +30,11 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0, scale=None):
 
 
 def fedavg_reduce_ref(x, w):
-    """x [C, N], w [C] -> [N]."""
-    return jnp.einsum("c,cn->n", w.astype(jnp.float32), x.astype(jnp.float32))
+    """x [C, N], w [C] -> [N], at full f32 precision on every backend."""
+    return jnp.einsum(
+        "c,cn->n", w.astype(jnp.float32), x.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def quantize_stochastic_ref(x, uniform, scale):

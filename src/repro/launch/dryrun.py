@@ -31,7 +31,7 @@ import jax  # noqa: E402
 
 from repro.configs import GRID_ARCHS, SHAPES_BY_NAME, TrainConfig, get_config  # noqa: E402
 from repro.launch.hlo_analysis import analyze_hlo  # noqa: E402
-from repro.launch.mesh import TPU_V5E, make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import TPU_V5E, make_production_mesh  # noqa: E402
 from repro.launch.roofline import derive  # noqa: E402
 from repro.launch.steps import build_outer_sync, build_step  # noqa: E402
 
@@ -114,7 +114,7 @@ def run_cell(
     t0 = time.time()
     try:
         built = build_step(cfg, tcfg, shape, mesh, mode=mode)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 built.fn,
                 in_shardings=built.in_shardings,
@@ -180,7 +180,7 @@ def run_outer_sync(arch: str, *, compression: str = "none") -> Dict:
     t0 = time.time()
     try:
         built = build_outer_sync(cfg, tcfg, mesh, compression=compression)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 built.fn,
                 in_shardings=built.in_shardings,

@@ -8,24 +8,11 @@ module never touches jax device state — the dry-run must set XLA_FLAGS
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older jax is Auto-only
-    from jax.sharding import AxisType
-
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-except ImportError:  # pragma: no cover - depends on installed jax
-
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
-def mesh_context(mesh):
-    """Ambient-mesh context across jax versions: jax.set_mesh when present
-    (jax >= 0.5), else the Mesh object's own context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

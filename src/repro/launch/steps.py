@@ -20,8 +20,6 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-
-from repro.launch.mesh import mesh_context
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -202,7 +200,7 @@ def build_train_step(
         "opt": state_abs,
         "step": jax.ShapeDtypeStruct((), jnp.int32),
     }
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         _, metrics_abs = jax.eval_shape(train_step, state_abs_full, inputs_abs)
     metrics_shard = jax.tree.map(lambda _: rep, metrics_abs)
 

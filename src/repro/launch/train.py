@@ -32,7 +32,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import TrainConfig, get_config, get_reduced
 from repro.configs.base import ShapeSpec
 from repro.data.tokens import token_batch_for
-from repro.launch.mesh import make_host_mesh, make_production_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import build_outer_sync, build_train_step, make_optimizer
 from repro.models import Model
 from repro.utils import tree_sub
@@ -72,7 +72,7 @@ def train(
     model = Model(cfg)
     opt = make_optimizer(tcfg)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(
             built.fn,
             in_shardings=built.in_shardings,

@@ -35,36 +35,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.base import silu
 
 
-def _ambient_mesh():
-    """Ambient mesh across jax versions (abstract mesh on jax >= 0.5, the
-    thread-resource physical mesh set by `with mesh:` on older jax)."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        return get_am()
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names, check_vma=True):
-    """shard_map across jax versions: `jax.shard_map(..., axis_names=...)`
-    on jax >= 0.5; the experimental API with the complementary `auto` set
-    (and check_vma spelled check_rep) on older jax."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return sm_old(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        auto=auto, check_rep=check_vma,
-    )
-
-
 def _route(cfg, p, x2d):
     """Top-k routing + aux under plain GSPMD. x2d [T, d] (any sharding)."""
     m = cfg.moe
@@ -146,8 +116,8 @@ def moe_forward_ep_a2a(cfg, p, x):
         out = _combine_local(yb, dest, token_idx, fg, keep, T, d, x_loc.dtype)
         return out.reshape(Bl, Sl, d)
 
-    mesh = _ambient_mesh()
-    fn = _shard_map(
+    mesh = jax.sharding.get_abstract_mesh()
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", "data", None), P("model", "data", None),
@@ -195,8 +165,8 @@ def moe_forward_local(cfg, p, x):
         out = _combine_local(yb, dest, token_idx, fg, keep, T, d, x_loc.dtype)
         return out.reshape(Bl, S, d)
 
-    mesh = _ambient_mesh()
-    fn = _shard_map(
+    mesh = jax.sharding.get_abstract_mesh()
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, "data", None), P(None, "data", None),
@@ -262,8 +232,8 @@ def moe_forward_ep_local(cfg, p, x):
         out = jax.lax.psum(out, "model")  # f32: bf16 psum crashes (see above)
         return out.astype(x_loc.dtype).reshape(Bl, S, d)
 
-    mesh = _ambient_mesh()
-    fn = _shard_map(
+    mesh = jax.sharding.get_abstract_mesh()
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", "data", None), P("model", "data", None),

@@ -5,21 +5,6 @@ import sys
 # separate process — never here)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:  # real hypothesis wins when installed
-    import hypothesis  # noqa: F401
-except ImportError:  # CI image lacks it: deterministic stand-in
-    import importlib.util as _ilu
-
-    _spec = _ilu.spec_from_file_location(
-        "hypothesis",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py"),
-    )
-    _mod = _ilu.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    _mod.strategies = _mod  # `from hypothesis import strategies as st`
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod
-
 import jax
 import jax.numpy as jnp
 import numpy as np

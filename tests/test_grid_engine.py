@@ -18,7 +18,7 @@ from repro.core import (
     run_fl_grid,
     trimmed_mean,
 )
-from repro.core.client import _ROW_BUCKETS, bucket_rows
+from repro.core.client import PLANE_ROWS, bucket_rows
 from repro.data import make_federated_mnist, synthetic_mnist
 from repro.transport import DEFAULT, LAB, TUNED_EDGE, sim_cohort_round, sim_grid_round
 
@@ -185,18 +185,18 @@ def test_plane_rows_width_and_position_independent():
 
 
 def test_bucket_rows_ladder():
-    assert bucket_rows(1) == 1
-    assert bucket_rows(5) == 6
-    assert bucket_rows(10) == 12
-    assert bucket_rows(128) == 128
-    assert bucket_rows(129) == 192  # past the ladder: multiples of 64
+    """Planes pad to whole blocks of PLANE_ROWS rows."""
+    assert bucket_rows(1) == PLANE_ROWS
+    assert bucket_rows(PLANE_ROWS) == PLANE_ROWS
+    assert bucket_rows(PLANE_ROWS + 1) == 2 * PLANE_ROWS
     for n in range(1, 200):
-        assert bucket_rows(n) >= n
+        assert bucket_rows(n) >= n and bucket_rows(n) % PLANE_ROWS == 0
+        assert bucket_rows(n) - n < PLANE_ROWS
 
 
 def test_plane_dispatches_use_bucket_widths():
-    """Chaos-variable cohort sizes land on the bucket ladder, bounding
-    compiled program count in client-failure sweeps."""
+    """Chaos-variable cohort sizes all dispatch PLANE_ROWS-row blocks: one
+    compiled program width for every client-failure sweep."""
     before = len(TASK.plane_dispatch_widths())
     kwargs = [
         dict(chaos=ChaosSchedule(LAB).add(client_failure_schedule(6, f, seed=11)),
@@ -206,8 +206,7 @@ def test_plane_dispatches_use_bucket_widths():
     run_fl_grid(TASK, [_point(**kw) for kw in kwargs], eval_data=EVAL)
     widths = TASK.plane_dispatch_widths()[before:]
     assert widths, "plane path did not run"
-    ladder = set(_ROW_BUCKETS)
-    assert all(w in ladder or w % 64 == 0 for w in widths), widths
+    assert set(widths) == {PLANE_ROWS}, widths
 
 
 def test_chunked_unroll_long_epochs_matches_sequential():

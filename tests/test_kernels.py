@@ -119,6 +119,44 @@ def test_fedavg_reduce_padding_sweep(N):
     assert jnp.allclose(got, expect, atol=1e-5)
 
 
+@pytest.mark.parametrize("C", [40, 300])
+def test_fedavg_reduce_wide_cohort(C):
+    """Cohorts wide enough to narrow the tile below its default."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (C, 5000))
+    w = jax.random.uniform(jax.random.PRNGKey(5), (C,)) + 0.05
+    got = ops.fedavg_reduce({"x": x}, w, interpret=True)["x"]
+    expect = ref.fedavg_reduce_ref(x, w / w.sum())
+    assert jnp.allclose(got, expect, atol=1e-5)
+
+
+# Row counts on both sides of the 32-row block (one block, several, a
+# partial last one) and leaf widths on both sides of the tile.
+ROW_LAYOUTS = [(2, 16), (10, 4608), (32, 2048), (33, 5000), (70, 144)]
+
+
+@pytest.mark.parametrize("R,N", ROW_LAYOUTS)
+def test_quantize_rows_multirow_bitwise(R, N):
+    x = jax.random.normal(jax.random.PRNGKey(R), (R, N)) * 0.1
+    scales = jnp.max(jnp.abs(x), axis=-1) * (1.0 / 127.0)
+    got = ops.quantize_rows(x, scales, interpret=True)
+    assert got.shape == (R, N) and got.dtype == jnp.int8
+    assert jnp.array_equal(got, ref.quantize_rows_ref(x, scales))
+
+
+@pytest.mark.parametrize("R,N", ROW_LAYOUTS)
+def test_downcast_bf16_rows_multirow_bitwise(R, N):
+    x = jax.random.normal(jax.random.PRNGKey(R), (R, N))
+    got = ops.downcast_bf16_rows(x, interpret=True)
+    assert got.shape == (R, N) and got.dtype == jnp.bfloat16
+    assert jnp.array_equal(got, ref.downcast_bf16_rows_ref(x))
+
+
+def test_row_kernels_reject_unaligned_tile():
+    x = jnp.zeros((2, 300))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        ops.downcast_bf16_rows(x, tile=100, interpret=True)
+
+
 @pytest.mark.parametrize("n", [100, 4096, 9999])
 def test_quantize_sweep(n):
     tree = {"a": jax.random.normal(jax.random.PRNGKey(0), (n,)) * 3.0}

@@ -9,7 +9,7 @@ import pytest
 from repro.configs import TrainConfig, get_reduced
 from repro.configs.base import ShapeSpec
 from repro.data.tokens import token_batch_for
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_decode_step, build_prefill_step, build_train_step
 from repro.models import Model
 
@@ -20,7 +20,7 @@ SMALL_DECODE = ShapeSpec("d", "decode", 32, 2)
 
 def _run_built(built, *concrete):
     mesh = make_host_mesh()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(
             built.fn,
             in_shardings=built.in_shardings,
