@@ -39,6 +39,7 @@ from repro.optim import (
     sgd,
 )
 from repro.utils import tree_stack, tree_sub
+from repro.utils.spans import span, to_host
 
 
 @dataclass
@@ -221,7 +222,7 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
 
 
 def _unstack_metrics(stacked: Dict[str, Any], n: int) -> List[Dict[str, float]]:
-    host = {k: np.asarray(v) for k, v in stacked.items()}  # one sync per metric
+    host = to_host(stacked, "fit_metrics")
     return [{k: float(v[i]) for k, v in host.items()} for i in range(n)]
 
 
@@ -253,19 +254,24 @@ def _row_blocks(anchors: Sequence[Any], anchor_idx, rows: Sequence[Any],
         )
 
 
-def _fit_blocks(runner, batches_for, anchors, rows, mus, use_prox, anchor_idx):
+def _fit_blocks(runner, batches_for, anchors, rows, steps, mus, use_prox, anchor_idx):
     """Run a plane block by block through ``runner``; ``batches_for(rows)``
-    builds one block's step batches. Returns (plane [bucket_rows(R), ...],
-    per-row last-step metrics [R])."""
+    builds one block's step batches on the host (numpy leaves
+    [PLANE_ROWS, steps, ...]), copied to the device here. Returns (plane
+    [bucket_rows(R), ...], per-row last-step metrics [R])."""
     planes, lasts = [], []
     for table, idx, blk_rows, blk_mus in _row_blocks(anchors, anchor_idx, rows, mus):
-        plane, last = runner(
-            tree_stack(table),
-            jnp.asarray(np.asarray(idx, np.int32)),
-            batches_for(blk_rows),
-            jnp.asarray(np.asarray(blk_mus, np.float32)),
-            use_prox,
-        )
+        with span("fit.batches", rows=len(blk_rows), steps=steps):
+            host = batches_for(blk_rows)
+        with span("fit.h2d") as s:
+            batches = {k: jnp.asarray(v) for k, v in host.items()}
+            s.set_metadata(bytes=sum(b.nbytes for b in batches.values()))
+        with span("fit.anchors", anchors=len(table)):
+            uanchor = tree_stack(table)
+            aidx = jnp.asarray(np.asarray(idx, np.int32))
+            mu = jnp.asarray(np.asarray(blk_mus, np.float32))
+        with span("fit.dispatch", rows=len(blk_rows), steps=steps):
+            plane, last = runner(uanchor, aidx, batches, mu, use_prox)
         planes.append(plane)
         lasts.append(last)
 
@@ -312,7 +318,7 @@ def _sgd_local_fit(loss_fn, lr: float, batch_size: int):
             )
             n_used += batch_size
         delta = tree_sub(params, anchor)
-        return delta, n_used, {k: float(v) for k, v in metrics.items()}
+        return delta, n_used, {k: float(v) for k, v in to_host(metrics, "fit_metrics").items()}
 
     return fit
 
@@ -332,13 +338,13 @@ def _sgd_plane_fns(cohort_loss_fn, lr: float, batch_size: int):
 
     def batches_for(rows):
         return {
-            "images": jnp.asarray(np.stack([c.dataset.images[p] for c, p in rows])),
-            "labels": jnp.asarray(np.stack([c.dataset.labels[p] for c, p in rows])),
+            "images": np.stack([c.dataset.images[p] for c, p in rows]),
+            "labels": np.stack([c.dataset.labels[p] for c, p in rows]),
         }
 
     def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
         plane, metrics = _fit_blocks(
-            runner, batches_for, anchors, rows, mus, use_prox, anchor_idx
+            runner, batches_for, anchors, rows, steps, mus, use_prox, anchor_idx
         )
         return plane, [steps * batch_size] * len(rows), metrics
 
@@ -358,7 +364,8 @@ def _plane_batched_local_fit(plan_fit, fit_rows):
         rng: np.random.Generator,
         prox_mu: float,
     ):
-        plans = plan_fit(clients, steps, rng)
+        with span("plan", rows=len(clients)):
+            plans = plan_fit(clients, steps, rng)
         rows = list(zip(clients, plans))
         plane, n_examples, metrics = fit_rows(
             [params], rows, steps, [prox_mu] * len(rows), prox_mu > 0,
@@ -385,7 +392,10 @@ def mnist_cnn_task(lr: float = 0.05, batch_size: int = 32) -> LocalTask:
         return acc, nll
 
     def evaluate(params, data: Dict[str, np.ndarray]):
-        acc, nll = ev(params, jnp.asarray(data["images"]), jnp.asarray(data["labels"]))
+        with span("evaluate", examples=len(data["labels"])):
+            acc, nll = to_host(
+                ev(params, jnp.asarray(data["images"]), jnp.asarray(data["labels"])), "eval"
+            )
         return {"accuracy": float(acc), "loss": float(nll)}
 
     plan_fit, plan_digest, fit_rows = _sgd_plane_fns(cnn_loss_stacked, lr, batch_size)
@@ -436,13 +446,15 @@ def lm_task(cfg, lr: float = 1e-3, batch_size: int = 4, seq: int = 64) -> LocalT
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
             params, opt_state, metrics = step(params, opt_state, batch)
         return tree_sub(params, anchor), steps * batch_size, {
-            k: float(v) for k, v in metrics.items()
+            k: float(v) for k, v in to_host(metrics, "fit_metrics").items()
         }
 
     def evaluate(params, data):
-        batch = token_batch_for(cfg, batch=batch_size, seq=seq, seed=7, client_id=10_000)
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        loss, metrics = jax.jit(loss_fn)(params, batch)
+        with span("evaluate", examples=batch_size):
+            batch = token_batch_for(cfg, batch=batch_size, seq=seq, seed=7, client_id=10_000)
+            batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            loss, metrics = jax.jit(loss_fn)(params, batch)
+            metrics = to_host(metrics, "eval")
         return {k: float(v) for k, v in metrics.items()}
 
     def cohort_loss(ps, batch):
@@ -472,14 +484,11 @@ def lm_task(cfg, lr: float = 1e-3, batch_size: int = 4, seq: int = 64) -> LocalT
                 for s in plan
             ]
             per_row.append({k: np.stack([b[k] for b in bs]) for k in bs[0]})
-        return {
-            k: jnp.asarray(np.stack([pr[k] for pr in per_row]))
-            for k in per_row[0]
-        }
+        return {k: np.stack([pr[k] for pr in per_row]) for k in per_row[0]}
 
     def fit_rows(anchors, rows, steps, mus, use_prox, anchor_idx=None):
         plane, metrics = _fit_blocks(
-            runner, batches_for, anchors, rows, mus, use_prox, anchor_idx
+            runner, batches_for, anchors, rows, steps, mus, use_prox, anchor_idx
         )
         return plane, [steps * batch_size] * len(rows), metrics
 

@@ -74,6 +74,7 @@ from repro.core.server import (
 from repro.core.strategy import Strategy
 from repro.transport import TcpParams
 from repro.transport.des import sim_grid_round
+from repro.utils.spans import span, to_host
 
 
 @dataclass
@@ -101,7 +102,6 @@ class GridStats:
     rounds: int = 0  # lockstep rounds with at least one plane row
     fit_rows_total: int = 0  # rows requested across all points
     fit_rows_unique: int = 0  # rows actually dispatched (pre-padding)
-    plane_dispatches: int = 0
     anchor_rows_stacked: int = 0  # unique anchors stacked across dispatches
     evals_requested: int = 0
     evals_computed: int = 0
@@ -231,12 +231,10 @@ def _plane_transport(
             if stats is not None:
                 stats.transport_device_dispatches += 1
             # one bulk materialization for the round's host bookkeeping
-            return (
-                np.asarray(out.success),
-                np.asarray(out.time, float),
-                np.asarray(out.reconnects),
-                np.asarray(out.bytes_acked, float),
+            succ, t, rc, ba = to_host(
+                (out.success, out.time, out.reconnects, out.bytes_acked), "transport"
             )
+            return succ, np.asarray(t, float), rc, np.asarray(ba, float)
         if mode == "parity":
             rng_kw = dict(rngs=[servers[i]._transport_rng for i, _ in sub])
         else:
@@ -528,10 +526,12 @@ def run_fl_grid(
 
         # --- transport plane: ONE stochastic sim_grid_round for the round --
         if waiting:
-            outcomes = _plane_transport(
-                waiting, servers, transport, transport_seed, rnd, stats
-            )
-            stats.transport_rows += sum(len(pr.cohort) for _, pr in waiting)
+            n_rows = sum(len(pr.cohort) for _, pr in waiting)
+            with span("transport", rows=n_rows):
+                outcomes = _plane_transport(
+                    waiting, servers, transport, transport_seed, rnd, stats
+                )
+            stats.transport_rows += n_rows
             for (i, pr), (succ, tt, rc, ba) in zip(waiting, outcomes):
                 job = servers[i].finish_transport(pr, succ, tt, rc, ba)
                 if job is not None:
@@ -548,58 +548,60 @@ def run_fl_grid(
                 res_keys[i] = intern(("opaque", next(nonce)))
                 srv.finish_round(job, stacked, deltas, weights, per_metrics)
                 continue
-            plans = task.plan_fit(job.clients, job.steps, srv.rng)
+            with span("plan", rows=len(job.clients)):
+                plans = task.plan_fit(job.clients, job.steps, srv.rng)
             pending.append((i, job, plans))
         if not pending:
             return
         stats.rounds += 1 if any(p[1].clients for p in pending) else 0
 
         # --- row table: coalesce identical rows across points ---------------
-        # groups keyed by the plane program's static axes (steps, use_prox)
-        groups: Dict[tuple, dict] = {}
-        placements = []  # (point_idx, job, group_key, row idxs, row keys)
-        for i, job, plans in pending:
-            if not job.clients:
-                # async drain-only tick (or a tick whose every flow
-                # failed): no rows to place, the post phase still runs it
-                placements.append((i, job, None, [], []))
-                continue
-            mu = float(job.prox_mu)
-            gkey = (job.steps, mu > 0)
-            g = groups.setdefault(
-                gkey,
-                {"index": {}, "aindex": {}, "anchors": [], "aidx": [],
-                 "rows": [], "mus": []},
-            )
-            idxs, row_keys = [], []
-            for client, plan in zip(job.clients, plans):
-                stats.fit_rows_total += 1
-                if coalesce:
-                    rkey = (
-                        params_keys[i],
-                        task.plan_digest(client, plan),
-                        job.steps,
-                        mu,
-                    )
-                else:
-                    rkey = ("row", next(nonce))
-                j = g["index"].get(rkey)
-                if j is None:
-                    j = len(g["rows"])
-                    g["index"][rkey] = j
-                    # anchors dedupe on params provenance (equal keys =>
-                    # bitwise-equal params); rows carry a gather index
-                    ai = g["aindex"].get(params_keys[i])
-                    if ai is None:
-                        ai = len(g["anchors"])
-                        g["aindex"][params_keys[i]] = ai
-                        g["anchors"].append(servers[i].global_params)
-                    g["aidx"].append(ai)
-                    g["rows"].append((client, plan))
-                    g["mus"].append(mu)
-                idxs.append(j)
-                row_keys.append(intern(rkey))
-            placements.append((i, job, gkey, idxs, row_keys))
+        with span("plan", rows=sum(len(job.clients) for _, job, _ in pending)):
+            # groups keyed by the plane program's static axes (steps, use_prox)
+            groups: Dict[tuple, dict] = {}
+            placements = []  # (point_idx, job, group_key, row idxs, row keys)
+            for i, job, plans in pending:
+                if not job.clients:
+                    # async drain-only tick (or a tick whose every flow
+                    # failed): no rows to place, the post phase still runs it
+                    placements.append((i, job, None, [], []))
+                    continue
+                mu = float(job.prox_mu)
+                gkey = (job.steps, mu > 0)
+                g = groups.setdefault(
+                    gkey,
+                    {"index": {}, "aindex": {}, "anchors": [], "aidx": [],
+                     "rows": [], "mus": []},
+                )
+                idxs, row_keys = [], []
+                for client, plan in zip(job.clients, plans):
+                    stats.fit_rows_total += 1
+                    if coalesce:
+                        rkey = (
+                            params_keys[i],
+                            task.plan_digest(client, plan),
+                            job.steps,
+                            mu,
+                        )
+                    else:
+                        rkey = ("row", next(nonce))
+                    j = g["index"].get(rkey)
+                    if j is None:
+                        j = len(g["rows"])
+                        g["index"][rkey] = j
+                        # anchors dedupe on params provenance (equal keys =>
+                        # bitwise-equal params); rows carry a gather index
+                        ai = g["aindex"].get(params_keys[i])
+                        if ai is None:
+                            ai = len(g["anchors"])
+                            g["aindex"][params_keys[i]] = ai
+                            g["anchors"].append(servers[i].global_params)
+                        g["aidx"].append(ai)
+                        g["rows"].append((client, plan))
+                        g["mus"].append(mu)
+                    idxs.append(j)
+                    row_keys.append(intern(rkey))
+                placements.append((i, job, gkey, idxs, row_keys))
 
         # --- plane dispatch: one fused program per group chunk --------------
         for gkey, g in groups.items():
@@ -628,7 +630,6 @@ def run_fl_grid(
                     anchor_idx=aidx_sub,
                 )
                 planes.append((plane, n_ex, mets))
-                stats.plane_dispatches += 1
             g["planes"] = planes
 
         # --- per-point post phase: scatter, aggregate, advance provenance ---
@@ -641,9 +642,10 @@ def run_fl_grid(
         for i, job, gkey, idxs, row_keys in placements:
             srv = servers[i]
             if idxs:
-                stacked, weights, per_metrics = _gather_rows(
-                    groups[gkey]["planes"], max_plane_rows, idxs
-                )
+                with span("gather_rows", rows=len(idxs)):
+                    stacked, weights, per_metrics = _gather_rows(
+                        groups[gkey]["planes"], max_plane_rows, idxs
+                    )
             else:  # async drain-only tick: no rows were placed
                 stacked, weights, per_metrics = None, [], []
             # fault domain first, BEFORE the shared compression pass can
@@ -875,7 +877,8 @@ def run_fl_grid(
         else min(max_rounds, stop_after_round)
     )
     for rnd in range(start_round, end_round):
-        _round(rnd)
+        with span("round", round=rnd):
+            _round(rnd)
         if mgr is not None and (rnd + 1) % checkpoint_every == 0:
             _save_checkpoint(mgr, rnd + 1)
             stats.checkpoints_saved += 1

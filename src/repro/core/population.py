@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro.core.client import EdgeClient
+from repro.utils.spans import span
 
 __all__ = ["Population"]
 
@@ -112,7 +113,9 @@ class Population:
                 raise ValueError(
                     f"client {cid} needs data but Population has no shard_factory"
                 )
-            c.dataset = self.shard_factory(cid)
+            with span("shard_build") as s:
+                c.dataset = self.shard_factory(cid)
+                s.set_metadata(examples=c.dataset.num_examples())
             self.shards_built += 1
         self._shard_lru[cid] = None
         self._shard_lru.move_to_end(cid)

@@ -58,6 +58,7 @@ from repro.transport.des import (
 )
 from repro.transport.params import RetryPolicy
 from repro.utils import tree_stack, tree_unstack
+from repro.utils.spans import span, to_host
 
 
 @dataclass
@@ -556,11 +557,14 @@ class FederatedServer:
                     key=transport_plane_key(cfg.seed, _TRANSPORT_STREAM, pending.rnd),
                     retry=self._effective_retry(),
                 )
+                succ, t, rc, ba = to_host(
+                    (out.success, out.time, out.reconnects, out.bytes_acked), "transport"
+                )
                 return (
-                    np.asarray(out.success)[0],
-                    np.asarray(out.time, float)[0],
-                    np.asarray(out.reconnects, float)[0],
-                    np.asarray(out.bytes_acked, float)[0],
+                    succ[0],
+                    np.asarray(t, float)[0],
+                    np.asarray(rc, float)[0],
+                    np.asarray(ba, float)[0],
                 )
             if cfg.engine == "fused_transport":
                 # opt-in shared-rng plane (sim_grid_round fused mode): the
@@ -678,19 +682,20 @@ class FederatedServer:
         (free — metrics are already on the host) or a non-finite stacked/
         listed delta sum (one fused device reduction; NaN/Inf propagate
         through a plain sum). Returns the cause string or None."""
-        for m in per_metrics:
-            v = m.get("loss")
-            if v is not None and not math.isfinite(float(v)):
-                return "non_finite_loss"
-        tree = stacked if stacked is not None else deltas
-        leaves = jax.tree.leaves(tree) if tree is not None else []
-        if leaves:
-            import jax.numpy as jnp
+        with span("divergence"):
+            for m in per_metrics:
+                v = m.get("loss")
+                if v is not None and not math.isfinite(float(v)):
+                    return "non_finite_loss"
+            tree = stacked if stacked is not None else deltas
+            leaves = jax.tree.leaves(tree) if tree is not None else []
+            if leaves:
+                import jax.numpy as jnp
 
-            total = float(sum(jnp.sum(leaf) for leaf in leaves))
-            if not math.isfinite(total):
-                return "non_finite_delta"
-        return None
+                total = float(to_host(sum(jnp.sum(leaf) for leaf in leaves), "divergence"))
+                if not math.isfinite(total):
+                    return "non_finite_delta"
+            return None
 
     def _quarantine_round(self, job: FitJob, cause: str) -> None:
         """Reject the round's update and retire the point: params and the
@@ -721,6 +726,13 @@ class FederatedServer:
         draws after) and the transport stream are both re-derived here,
         fold_in-keyed on (seed, stream, round) — which is what makes the
         selection sequence bitwise invariant to the transport engine."""
+        with span("select") as s:
+            pending = self._select_cohort(rnd)
+            if pending is not None:
+                s.set_metadata(cohort=len(pending.cohort))
+            return pending
+
+    def _select_cohort(self, rnd: int) -> Optional[PendingRound]:
         cfg = self.config
         if self.split_streams:
             self.rng = derive_rng(cfg.seed, _COHORT_STREAM, rnd)
@@ -837,23 +849,24 @@ class FederatedServer:
         if len(pending.cohort) == 0:  # async drain-only tick
             z = np.zeros(0, float)
             return np.zeros(0, bool), z, z, z
-        if self.config.batched:
-            return self._cohort_transport(pending)
-        comp, times, recon, acked = [], [], [], []
-        for client, link, lt in zip(pending.cohort, pending.links, pending.local_times):
-            done, ct, rc, ba = self._client_transport(
-                client, link, float(lt), pending.upload_bytes, pending.download_bytes
+        with span("transport", rows=len(pending.cohort)):
+            if self.config.batched:
+                return self._cohort_transport(pending)
+            comp, times, recon, acked = [], [], [], []
+            for client, link, lt in zip(pending.cohort, pending.links, pending.local_times):
+                done, ct, rc, ba = self._client_transport(
+                    client, link, float(lt), pending.upload_bytes, pending.download_bytes
+                )
+                comp.append(done)
+                times.append(ct)
+                recon.append(rc)
+                acked.append(ba)
+            return (
+                np.array(comp, bool),
+                np.array(times, float),
+                np.array(recon, float),
+                np.array(acked, float),
             )
-            comp.append(done)
-            times.append(ct)
-            recon.append(rc)
-            acked.append(ba)
-        return (
-            np.array(comp, bool),
-            np.array(times, float),
-            np.array(recon, float),
-            np.array(acked, float),
-        )
 
     def _record_bytes(self, record: RoundRecord, completed, bytes_acked) -> None:
         """Fold partial-progress telemetry into the round record: total
@@ -878,11 +891,19 @@ class FederatedServer:
         point's row slice of the grid driver's fused transport plane;
         ``bytes_acked`` (optional, [k]) carries the exchanges' acked
         frontiers into the round's wasted-work telemetry."""
-        cfg = self.config
-        if cfg.async_mode:
-            return self._finish_transport_async(
+        with span("finish_transport", rows=len(pending.cohort)):
+            if self.config.async_mode:
+                return self._finish_transport_async(
+                    pending, completed, times, reconnects, bytes_acked
+                )
+            return self._finish_transport_sync(
                 pending, completed, times, reconnects, bytes_acked
             )
+
+    def _finish_transport_sync(
+        self, pending: PendingRound, completed, times, reconnects, bytes_acked
+    ) -> Optional[FitJob]:
+        cfg = self.config
         record = pending.record
         quorum = self.strategy.quorum(len(self.clients))
         record.reconnects += float(np.sum(np.asarray(reconnects, float)))
@@ -1061,6 +1082,14 @@ class FederatedServer:
         billed by the transport phase via ``PendingRound.download_bytes``.
         Consumes no RNG: everything stochastic about a round happens in
         ``begin_round``/``execute_fit``."""
+        with span("finish_round", rows=len(job.clients)):
+            self._finish_round(job, stacked, deltas, weights, per_metrics,
+                               precompressed, fault_checked)
+
+    def _finish_round(
+        self, job: FitJob, stacked, deltas, weights, per_metrics,
+        precompressed: bool, fault_checked: bool,
+    ) -> None:
         cfg = self.config
         rnd = job.rnd
         record = job.record
@@ -1344,10 +1373,11 @@ class FederatedServer:
         for rnd in range(start_round, end_round):
             if self.terminated:
                 break
-            job = self.begin_round(rnd)
-            if job is not None:
-                stacked, deltas, weights, per_metrics = self.execute_fit(job)
-                self.finish_round(job, stacked, deltas, weights, per_metrics)
+            with span("round", round=rnd):
+                job = self.begin_round(rnd)
+                if job is not None:
+                    stacked, deltas, weights, per_metrics = self.execute_fit(job)
+                    self.finish_round(job, stacked, deltas, weights, per_metrics)
             if mgr is not None and (rnd + 1) % checkpoint_every == 0:
                 self._save_checkpoint(mgr, rnd + 1)
         return self.history

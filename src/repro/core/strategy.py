@@ -21,6 +21,7 @@ import numpy as np
 
 from repro.kernels import ops as kernel_ops
 from repro.optim import Optimizer, fedopt_server, nesterov_outer
+from repro.utils.spans import span
 from repro.utils import (
     tree_add,
     tree_scale,
@@ -66,10 +67,11 @@ class Strategy:
         """Batched-engine entry: deltas arrive stacked along a leading client
         axis; the weighted-mean family reduces them in one kernel pass with
         no per-client scaled copies."""
-        if self.stacked_aggregate_fn is None:
-            return self.aggregate(global_params, tree_unstack(stacked_deltas), weights, step)
-        agg = self.stacked_aggregate_fn(stacked_deltas, weights)
-        return self._apply(global_params, agg, step)
+        with span("aggregate", rows=len(weights)):
+            if self.stacked_aggregate_fn is None:
+                return self.aggregate(global_params, tree_unstack(stacked_deltas), weights, step)
+            agg = self.stacked_aggregate_fn(stacked_deltas, weights)
+            return self._apply(global_params, agg, step)
 
     def _apply(self, global_params, agg_delta, step: int):
         if self.server_opt is None:
