@@ -73,9 +73,9 @@ class LocalTask:
     # coefficients, and Rb is R padded up to whole PLANE_ROWS blocks
     # (callers slice/gather the rows they own). ``anchors`` is a list of
     # UNIQUE per-row params pytrees and ``anchor_idx`` maps each row to its
-    # anchor — each block stacks only the anchors its rows reference and
-    # gathers rows inside the jit. ``anchor_idx=None`` means anchors is
-    # per-row (len R, identity mapping). One fused dispatch per block
+    # anchor — each block passes only the anchors its rows reference, and
+    # the fit program stacks and gathers them. ``anchor_idx=None`` means
+    # anchors is per-row (len R, identity mapping). One fused dispatch per block
     # (chunked past _UNROLL_LIMIT steps).
     fit_rows: Optional[Callable] = None
 
@@ -114,12 +114,15 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
     batch leaf carries a leading row axis R. Summing the per-row losses
     before differentiation yields each row's own gradient in its slice
     (rows share no parameters), so one value_and_grad drives R independent
-    SGD trajectories. Anchors arrive as a stack of UNIQUE params trees
-    [U, ...] plus a per-row gather index [R] (each row may start from
+    SGD trajectories. Anchors arrive as a table of exactly PLANE_ROWS
+    params trees (the block's UNIQUE anchors, padded by repeating the
+    first) plus a per-row gather index [R] (each row may start from
     different global params — the grid engine mixes sweep points in one
-    plane — but most planes reference only 1-3 distinct anchors, so the
-    dispatch transfers O(U x params) and the [R, ...] anchor view is a
-    gather inside the jit); ``mu`` is a per-row prox coefficient.
+    plane — but most blocks reference only 1-3 distinct anchors). The
+    table is stacked and gathered into the [R, ...] anchor view inside the
+    program, so a block costs no eager device op per leaf, and its fixed
+    length keeps one program per (steps, prox); ``mu`` is a per-row prox
+    coefficient.
     Clipping is per-row (clip_by_global_norm_stacked); the momentum update
     is leaf-wise and vectorizes over the stacked axis unchanged.
 
@@ -156,12 +159,12 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
         updates, opt_state = opt.update(grads, opt_state, stacked, jnp.int32(0))
         return apply_updates(stacked, updates), opt_state, metrics
 
-    def _gather_anchor(uanchor, aidx):
-        return jax.tree.map(lambda l: jnp.take(l, aidx, axis=0), uanchor)
+    def _gather_anchor(table, aidx):
+        return jax.tree.map(lambda l: jnp.take(l, aidx, axis=0), tree_stack(table))
 
     @functools.partial(jax.jit, static_argnames=("use_prox", "steps"))
-    def fit_fused(uanchor, aidx, batches, mu, use_prox, steps):
-        anchor = _gather_anchor(uanchor, aidx)
+    def fit_fused(table, aidx, batches, mu, use_prox, steps):
+        anchor = _gather_anchor(table, aidx)
         stacked = anchor
         opt_state = opt.init(stacked)
         metrics = {}
@@ -186,26 +189,26 @@ def _plane_sgd_runner(cohort_loss_fn, lr: float):
         return stacked, opt_state, metrics
 
     @jax.jit
-    def init_state(uanchor, aidx):
+    def init_state(table, aidx):
         # materialize the gathered [R, ...] anchor once: the chunk loop
         # donates its carry, the anchor must survive for the prox term and
         # the final delta
-        anchor = _gather_anchor(uanchor, aidx)
+        anchor = _gather_anchor(table, aidx)
         return jax.tree.map(jnp.copy, anchor), opt.init(anchor), anchor
 
     @jax.jit
     def finalize(stacked, anchor):
         return jax.tree.map(jnp.subtract, stacked, anchor)
 
-    def run_rows(uanchor, aidx, batches, mu, use_prox):
-        # uanchor: pytree with leaves [U, ...] (unique anchors); aidx: [R]
-        # row->anchor gather index; batches: leaves [R, steps, ...]
+    def run_rows(table, aidx, batches, mu, use_prox):
+        # table: tuple of PLANE_ROWS params trees; aidx: [R] row->table
+        # gather index; batches: leaves [R, steps, ...]
         leaves = jax.tree.leaves(batches)
         r, steps = leaves[0].shape[:2]
         run_rows.dispatch_widths.append(int(r))
         if steps <= _UNROLL_LIMIT:
-            return fit_fused(uanchor, aidx, batches, mu, use_prox, steps)
-        stacked, opt_state, anchor = init_state(uanchor, aidx)
+            return fit_fused(table, aidx, batches, mu, use_prox, steps)
+        stacked, opt_state, anchor = init_state(table, aidx)
         metrics = {}
         s = 0
         while s < steps:
@@ -230,10 +233,10 @@ def _row_blocks(anchors: Sequence[Any], anchor_idx, rows: Sequence[Any],
                 mus: Sequence[float]):
     """Split a plane into PLANE_ROWS-row blocks.
 
-    Yields (anchor table, anchor_idx, rows, mus) per block, each padded to
-    PLANE_ROWS by repeating its first entry. A block's table holds only the
-    anchors its rows reference, in first-use order; padding anchors are
-    never gathered and padding rows' results are discarded.
+    Yields (anchor table, anchor_idx, rows, mus) per block. A block's table
+    holds only the distinct anchors its rows reference, in first-use order;
+    the index, rows and mus are padded to PLANE_ROWS by repeating their
+    first entry, and padding rows' results are discarded.
     ``anchor_idx=None`` means anchors is per-row (identity mapping).
     """
     aidx = list(range(len(rows)) if anchor_idx is None else anchor_idx)
@@ -241,13 +244,12 @@ def _row_blocks(anchors: Sequence[Any], anchor_idx, rows: Sequence[Any],
         local: Dict[int, int] = {}
         for a in aidx[s : s + PLANE_ROWS]:
             local.setdefault(a, len(local))
-        table = [anchors[a] for a in local]
         idx = [local[a] for a in aidx[s : s + PLANE_ROWS]]
         blk_rows = list(rows[s : s + PLANE_ROWS])
         blk_mus = [float(m) for m in mus[s : s + PLANE_ROWS]]
         pad = PLANE_ROWS - len(blk_rows)
         yield (
-            table + [table[0]] * (PLANE_ROWS - len(table)),
+            [anchors[a] for a in local],
             idx + [idx[0]] * pad,
             blk_rows + [blk_rows[0]] * pad,
             blk_mus + [blk_mus[0]] * pad,
@@ -257,8 +259,11 @@ def _row_blocks(anchors: Sequence[Any], anchor_idx, rows: Sequence[Any],
 def _fit_blocks(runner, batches_for, anchors, rows, steps, mus, use_prox, anchor_idx):
     """Run a plane block by block through ``runner``; ``batches_for(rows)``
     builds one block's step batches on the host (numpy leaves
-    [PLANE_ROWS, steps, ...]), copied to the device here. Returns (plane
-    [bucket_rows(R), ...], per-row last-step metrics [R])."""
+    [PLANE_ROWS, steps, ...]), copied to the device here. The anchor table
+    goes to the program as PLANE_ROWS references (padded by repeating the
+    first anchor, so the program's inputs keep one shape) and the index and
+    mus as numpy arrays: the program stacks and gathers them. Returns
+    (plane [bucket_rows(R), ...], per-row last-step metrics [R])."""
     planes, lasts = [], []
     for table, idx, blk_rows, blk_mus in _row_blocks(anchors, anchor_idx, rows, mus):
         with span("fit.batches", rows=len(blk_rows), steps=steps):
@@ -267,11 +272,11 @@ def _fit_blocks(runner, batches_for, anchors, rows, steps, mus, use_prox, anchor
             batches = {k: jnp.asarray(v) for k, v in host.items()}
             s.set_metadata(bytes=sum(b.nbytes for b in batches.values()))
         with span("fit.anchors", anchors=len(table)):
-            uanchor = tree_stack(table)
-            aidx = jnp.asarray(np.asarray(idx, np.int32))
-            mu = jnp.asarray(np.asarray(blk_mus, np.float32))
+            table = tuple(table + [table[0]] * (PLANE_ROWS - len(table)))
+            aidx = np.asarray(idx, np.int32)
+            mu = np.asarray(blk_mus, np.float32)
         with span("fit.dispatch", rows=len(blk_rows), steps=steps):
-            plane, last = runner(uanchor, aidx, batches, mu, use_prox)
+            plane, last = runner(table, aidx, batches, mu, use_prox)
         planes.append(plane)
         lasts.append(last)
 
@@ -354,8 +359,8 @@ def _sgd_plane_fns(cohort_loss_fn, lr: float, batch_size: int):
 
 def _plane_batched_local_fit(plan_fit, fit_rows):
     """Default cohort-batched fit on top of the plane API: every row shares
-    the cohort's single anchor (stacked once, gathered per row inside the
-    jit); the plane is sliced back to cohort width."""
+    the cohort's single anchor (gathered per row inside the fit program);
+    the plane is sliced back to cohort width."""
 
     def fit_cohort(
         params,

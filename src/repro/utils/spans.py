@@ -19,7 +19,7 @@ Open a run inside ``jax.profiler.trace(logdir)`` and read these in Perfetto
 - ``fl.plan`` [rows]: batch plans (``plan_fit``) and the grid's coalescing row table.
 - ``fl.fit.batches`` [rows, steps]: the host gather and stack of one fit block's examples.
 - ``fl.fit.h2d`` [bytes]: the copy of that block's examples to the device.
-- ``fl.fit.anchors`` [anchors]: stacking the block's anchors, and its row index and prox arrays.
+- ``fl.fit.anchors`` [anchors]: the host build of one block's anchor table (its distinct anchors), row index and prox arrays.
 - ``fl.fit.dispatch`` [rows, steps]: the fit program's call for one block.
 - ``fl.gather_rows`` [rows]: the grid's gather of one point's rows from the fit planes.
 - ``fl.divergence``: ``FederatedServer._divergence_cause``, the quarantine check.

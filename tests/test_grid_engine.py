@@ -19,6 +19,7 @@ from repro.core import (
     trimmed_mean,
 )
 from repro.core.client import PLANE_ROWS, bucket_rows
+from repro.utils import tree_stack
 from repro.data import make_federated_mnist, synthetic_mnist
 from repro.transport import DEFAULT, LAB, TUNED_EDGE, sim_cohort_round, sim_grid_round
 
@@ -225,6 +226,68 @@ def test_chunked_unroll_long_epochs_matches_sequential():
             assert float(jnp.max(jnp.abs(a[i] - b))) < 5e-4
         assert abs(metrics[i]["loss"] - m["loss"]) < 1e-3
     assert r_bat.integers(0, 2**31) == r_seq.integers(0, 2**31)
+
+
+def _block_rows(task, steps, seed):
+    clients = [EdgeClient(i, dataset=SHARDS[i % len(SHARDS)]) for i in range(PLANE_ROWS)]
+    return list(zip(clients, task.plan_fit(clients, steps, np.random.default_rng(seed))))
+
+
+def _distinct_anchors(n):
+    base = TASK.init_fn(jax.random.PRNGKey(2))
+    return [jax.tree.map(lambda l, k=k: l + 0.01 * k, base) for k in range(n)]
+
+
+@pytest.mark.parametrize("steps", [4, 20])  # fused; chunked past _UNROLL_LIMIT
+@pytest.mark.parametrize("n_anchors", [1, 3, 8])
+def test_anchor_table_stacked_in_program_matches_eager_stack(n_anchors, steps):
+    """The fit program stacks a block's table of distinct anchors and
+    gathers each row's anchor itself. Its plane equals, bitwise, the plane
+    from an eager tree_stack of the same table, gathered per row on the
+    host and fed to the runner with the identity index."""
+    rows = _block_rows(TASK, steps, seed=4)
+    anchors = _distinct_anchors(n_anchors)
+    idx = [i % n_anchors for i in range(PLANE_ROWS)]
+    mus = [0.01] * PLANE_ROWS
+    plane, _, metrics = TASK.fit_rows(anchors, rows, steps, mus, True, anchor_idx=idx)
+
+    eager = tree_stack(anchors)
+    per_row = [jax.tree.map(lambda l, i=i: l[i], eager) for i in idx]
+    ref, _, ref_metrics = TASK.fit_rows(per_row, rows, steps, mus, True)
+    for a, b in zip(jax.tree.leaves(plane), jax.tree.leaves(ref)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert metrics == ref_metrics
+
+
+def test_anchor_table_keeps_one_fit_program():
+    """Blocks with 1, 2 and 8 distinct anchors share one compiled fit_fused
+    program at one (steps, use_prox), and a repeated fit_rows call compiles
+    nothing: counted by jax.monitoring backend-compile events, as the
+    benchmark's jit.compiles_in_window counts them."""
+    task = mnist_cnn_task()  # fresh jit caches
+    rows = _block_rows(task, 1, seed=6)
+    mus = [0.0] * PLANE_ROWS
+    calls = [
+        (_distinct_anchors(n), [i % n for i in range(PLANE_ROWS)]) for n in (1, 2, 8)
+    ]
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for anchors, idx in calls:
+            task.fit_rows(anchors, rows, 1, mus, False, anchor_idx=idx)
+        assert compiled == ["jit(fit_fused)"], compiled
+        compiled.clear()
+        anchors, idx = calls[1]
+        task.fit_rows(anchors, rows, 1, mus, False, anchor_idx=idx)
+        assert compiled == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert task.plane_dispatch_widths() == [PLANE_ROWS] * 4
 
 
 # ---------------------------------------------------------------------------
