@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness import flops
+
 COHORT_STREAM = 1  # the selection-then-plans stream tag under split streams
 BLOCK = 8  # clients per reference program (padded; one compile per shape)
 
@@ -53,6 +55,12 @@ def init_from_key(key):
 
 def init_params(seed: int):
     return init_from_key(jax.random.PRNGKey(seed))
+
+
+def flops_per_example(cfg) -> Dict[str, float]:
+    """FLOPs of one example: in an SGD step (forward and backward) and in
+    an eval forward pass."""
+    return {"train": flops.TRAIN_FACTOR * flops.CNN_FORWARD_FLOPS, "eval": flops.CNN_FORWARD_FLOPS}
 
 
 def forward(p, images):
@@ -112,7 +120,7 @@ def _cast(tree, dtype):
 def fedavg_round(p, shards: Sequence, plans: Sequence[np.ndarray], *, lr: float,
                  momentum: float, clip: float, dtype=jnp.float32):
     """New global params after one FedAvg round over the delivering clients
-    ``shards`` [(images, labels)] with batch plans [steps, B] each."""
+    ``shards`` [{"images", "labels"}] with batch plans [steps, B] each."""
     n = len(shards)
     weights = np.array([plan.size for plan in plans], np.float64)
     weights = weights / weights.sum()
@@ -120,8 +128,10 @@ def fedavg_round(p, shards: Sequence, plans: Sequence[np.ndarray], *, lr: float,
     for s in range(0, n, BLOCK):
         idx = list(range(s, min(s + BLOCK, n)))
         pad = BLOCK - len(idx)
-        imgs = np.stack([shards[i][0][plans[i]] for i in idx] + [shards[idx[0]][0][plans[idx[0]]]] * pad)
-        labs = np.stack([shards[i][1][plans[i]] for i in idx] + [shards[idx[0]][1][plans[idx[0]]]] * pad)
+        imgs = np.stack([shards[i]["images"][plans[i]] for i in idx]
+                        + [shards[idx[0]]["images"][plans[idx[0]]]] * pad)
+        labs = np.stack([shards[i]["labels"][plans[i]] for i in idx]
+                        + [shards[idx[0]]["labels"][plans[idx[0]]]] * pad)
         w = np.concatenate([weights[idx], np.zeros(pad)]).astype(np.float32)
         part = _block_sum(p, jnp.asarray(imgs, dtype), jnp.asarray(labs), jnp.asarray(w),
                           lr=lr, momentum=momentum, clip=clip)
@@ -163,7 +173,7 @@ def replay(point, rounds: Sequence[int], *, lr: float, momentum: float, clip: fl
     ``point`` gives ``seed``, ``split`` (stream discipline), ``n_live`` and
     ``k`` (cohort draw), ``analytic_draws`` (the analytic transport's one
     uniform per cohort member on the single stream), ``batch``, ``steps``,
-    ``shard(cid) -> (images, labels)``, ``eval_data``, ``compressor``
+    ``shard(cid) -> {"images", "labels"}``, ``eval_data``, ``compressor``
     (only "none" is modelled) and, per round, ``delivered[r]``: the
     delivering client ids in delivery order, None for a failed round.
 
@@ -193,7 +203,7 @@ def replay(point, rounds: Sequence[int], *, lr: float, momentum: float, clip: fl
                 out.append({"cohort": [int(c) for c in cohort], "params": p, "loss": None})
                 continue
             shards = [point["shard"](c) for c in ids]
-            plans = [batch_plan(rng, len(s[1]), point["batch"], point["steps"]) for s in shards]
+            plans = [batch_plan(rng, len(s["labels"]), point["batch"], point["steps"]) for s in shards]
             p = fedavg_round(p, shards, plans, lr=lr, momentum=momentum, clip=clip, dtype=dtype)
             out.append({"cohort": [int(c) for c in cohort], "params": p,
                         "loss": eval_loss(p, point["eval_data"], dtype)})

@@ -1,6 +1,8 @@
 """Operations and bytes from shapes, for the utilization and roofline
 metrics. Counted from what the algorithm needs, not from what the program
-happens to run: padding rows and recomputation are not counted.
+happens to run: padding rows and recomputation are not counted. A
+payload's FLOPs per example come from its configuration's reference
+(``flops_per_example``); the CNN's constants are here for its reference.
 """
 
 from __future__ import annotations
@@ -14,12 +16,6 @@ FC1_FLOPS = 2 * (32 * 7 * 7) * 128  # 401,408
 FC2_FLOPS = 2 * 128 * 10  # 2,560
 CNN_FORWARD_FLOPS = CONV1_FLOPS + CONV2_FLOPS + FC1_FLOPS + FC2_FLOPS  # 2,436,096
 TRAIN_FACTOR = 3  # forward + backward (input and weight gradients)
-
-
-def cnn_flops(trained_examples: float, evaluated_examples: float) -> float:
-    """FLOPs of training ``trained_examples`` (one SGD step each) and of
-    evaluating ``evaluated_examples``."""
-    return CNN_FORWARD_FLOPS * (TRAIN_FACTOR * trained_examples + evaluated_examples)
 
 
 def _rows_cols(shape: Sequence[int]):

@@ -19,7 +19,8 @@ A traced run installs besides:
   rows, the divergence check, aggregation, eval, ``finish_transport``,
   ``finish_round``, server construction and the building of each sweep's
   points;
-- counters: fit rows trained, fit dispatches, eval examples, kernel bytes
+- counters: fit rows trained, fit dispatches, eval examples (the eval
+  data's leading axis), kernel bytes
   from shapes, time in selection, and XLA compilations or compile-cache
   loads (``jax.monitoring``).
 """
@@ -33,6 +34,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+
+from harness.data import examples
 
 SPAN_PREFIX = "bench."
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -204,7 +207,7 @@ class Probe:
 
         def evaluate(orig):
             def f(params, data):
-                probe.count("eval_examples", len(data["labels"]))
+                probe.count("eval_examples", examples(data))
                 with probe.span("evaluate"):
                     return orig(params, data)
             return f

@@ -12,9 +12,9 @@ are the program's two entry points, ``run_fl_grid`` and
 
 Everything else is data: links and TCP presets are files of their own
 (``bench/links/<name>.json``, ``bench/tcp/<name>.json``) that state every
-field the flow reference reads; the partition, task, strategy and
-matmul precision come from the configuration; a traffic file's
-``server``, ``chaos`` and ``grid`` objects pass through to
+field the flow reference reads; the data (``harness.data``), payload
+task, strategy and matmul precision come from the configuration; a
+traffic file's ``server``, ``chaos`` and ``grid`` objects pass through to
 ``ServerConfig``, ``ChaosSchedule`` events and ``run_fl_grid``.
 
 The ladders and the point factory are copies of ``benchmarks/fig3_latency``,
@@ -52,36 +52,60 @@ def sweep_seeds(seed: int, sweep: int, n: int, how: str) -> List[int]:
     return [int(c.generate_state(1)[0]) for c in ss.spawn(n)]
 
 
+def leaves(params) -> Dict[str, Any]:
+    """Every leaf of a parameter tree by its ``/``-joined path
+    (``conv1/w``, ``seg1/mlp/w_gate``)."""
+    import jax
+
+    def name(k):
+        return str(next(getattr(k, a) for a in ("key", "idx", "name") if hasattr(k, a)))
+
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    return {"/".join(name(k) for k in path): leaf for path, leaf in flat}
+
+
+def check_params(cfg: Dict[str, Any], params, who: str) -> None:
+    """``params`` (shapes will do) against the configuration: each path of
+    ``layers`` at its shape, the same top-level entries, ``params``
+    parameters in all, every leaf in ``dtype``."""
+    got = leaves(params)
+    errors = []
+    for path, shape in cfg["layers"].items():
+        if path not in got:
+            errors.append(f"no leaf {path}")
+        elif list(got[path].shape) != list(shape):
+            errors.append(f"{path} is {list(got[path].shape)}, not {list(shape)}")
+    top, stated = ({p.split("/")[0] for p in paths} for paths in (got, cfg["layers"]))
+    if top != stated:
+        errors.append(f"top-level entries {sorted(top)}, not {sorted(stated)}")
+    count = sum(int(np.prod(l.shape)) for l in got.values())
+    if count != cfg["params"]:
+        errors.append(f"{count} parameters, not {cfg['params']}")
+    dtypes = sorted({str(l.dtype) for l in got.values()})
+    if dtypes != [cfg["dtype"]]:
+        errors.append(f"dtypes {dtypes}, not {cfg['dtype']}")
+    if errors:
+        raise ValueError(f"{cfg['model']} parameters of {who} differ from the configuration's: "
+                         + "; ".join(errors))
+
+
 def make_task(cfg: Dict[str, Any], init_fn):
-    """The configuration's payload task (``repro.core.<model>_task``),
-    starting from the benchmark's weights; its parameters have to be the
-    configuration's layers and count, in its dtype."""
+    """The configuration's payload task (``repro.core.<model>_task``, given
+    ``lr``, ``batch_size`` and, where the configuration states it,
+    ``seq_len``), starting from the benchmark's weights; the task's and
+    the reference's parameters have to be the configuration's."""
     import jax
 
     import repro.core
 
-    task = getattr(repro.core, f"{cfg['model']}_task")(lr=cfg["lr"], batch_size=cfg["batch_size"])
-    shapes = jax.eval_shape(task.init_fn, jax.random.PRNGKey(0))
-    for params in (shapes, jax.eval_shape(init_fn, jax.random.PRNGKey(0))):
-        got = {k: list(v["w"].shape) for k, v in params.items()}
-        leaves = jax.tree.leaves(params)
-        if (got != cfg["layers"] or sum(int(np.prod(l.shape)) for l in leaves) != cfg["params"]
-                or any(str(l.dtype) != cfg["dtype"] for l in leaves)):
-            raise ValueError(f"{cfg['model']} parameters {got} differ from the configuration's")
+    kw = dict(lr=cfg["lr"], batch_size=cfg["batch_size"])
+    if "seq_len" in cfg:
+        kw["seq_len"] = cfg["seq_len"]
+    task = getattr(repro.core, f"{cfg['model']}_task")(**kw)
+    key = jax.random.PRNGKey(0)
+    check_params(cfg, jax.eval_shape(task.init_fn, key), "the task")
+    check_params(cfg, jax.eval_shape(init_fn, key), "the reference")
     return dataclasses.replace(task, init_fn=init_fn)
-
-
-def _shards(cfg: Dict[str, Any], seed: int, protos):
-    """Client id -> (images, labels), by the configuration's partition."""
-    n = cfg["examples_per_client"]
-    if cfg["partition"] == "dirichlet":
-        return lambda c: data.client_shard([seed, 2], c, n, cfg["dirichlet_alpha"], protos)
-    if cfg["partition"] == "iid" and "n_clients" in cfg:
-        pool = data.iid_shards(cfg["n_clients"], n, [seed, 0])
-        return lambda c: pool[c]
-    if cfg["partition"] == "iid":
-        return lambda c: data.client_shard([seed, 2], c, n, None, protos)
-    raise ValueError(f"unknown partition {cfg['partition']!r}")
 
 
 class _Traffic:
@@ -89,8 +113,7 @@ class _Traffic:
 
     def __init__(self, cfg: Dict[str, Any], traffic: Dict[str, Any], seed: int):
         self.cfg, self.traffic, self.seed = cfg, traffic, int(seed)
-        self.shard = _shards(cfg, self.seed, data.prototypes())
-        self.eval_data = data.synthetic_mnist(cfg["eval_examples"], [self.seed, 1])
+        self.shard, self.eval_data = data.source(cfg, self.seed)
         self.server_kw = dict(traffic["server"], local_steps=cfg["local_steps"],
                               round_deadline=cfg["round_deadline"],
                               base_step_cost=cfg["base_step_cost"])
@@ -134,10 +157,8 @@ class GridTraffic(_Traffic):
     engine = "grid"
 
     def __init__(self, cfg: Dict[str, Any], traffic: Dict[str, Any], seed: int):
-        from repro.data import ClientDataset
-
         super().__init__(cfg, traffic, seed)
-        self.datasets = [ClientDataset(i, *self.shard(i)) for i in range(cfg["n_clients"])]
+        self.datasets = [data.dataset(i, self.shard(i)) for i in range(cfg["n_clients"])]
         base = spec("links", traffic["link"])
         self.specs = [
             (dict(base, **{traffic["axis"]: v, "name": f"{traffic['axis']}{v}"}), spec("tcp", t))
@@ -206,7 +227,6 @@ class PopulationTraffic(_Traffic):
 
     def server(self, task):
         from repro.core import FederatedServer, Population, ServerConfig
-        from repro.data import ClientDataset
         from repro.transport import TcpParams
 
         cfg = self.cfg
@@ -216,7 +236,7 @@ class PopulationTraffic(_Traffic):
             raise ValueError(f"quorum {strategy.quorum(n)} != goal {goal}")
         return FederatedServer(
             task,
-            Population(n, lambda c: ClientDataset(int(c), *self.shard(c)),
+            Population(n, lambda c: data.dataset(c, self.shard(c)),
                        max_cached_shards=self.traffic["max_cached_shards"]),
             strategy,
             tcp=TcpParams(**self.tcp),

@@ -344,7 +344,9 @@ def transport(cell: Dict, run, flow_mod, seed: int, quorum: int, goal) -> Dict[s
     return worst
 
 
-def per_layer(cell: Dict, probe, win: Window, device_kind: str, rounds: int):
+def per_layer(cell: Dict, probe, win: Window, device_kind: str, rounds: int, reference):
+    """Every per-layer metric of the cell that its reader finds, from the
+    traced window; ``reference`` is the configuration's reference module."""
     from harness import flops, trace
 
     tr = trace.collect(win.logdir)
@@ -356,7 +358,7 @@ def per_layer(cell: Dict, probe, win: Window, device_kind: str, rounds: int):
         trace=tr, lo=lo, hi=hi, busy_s=busy_s, window_s=window_s, rounds=rounds,
         counters=dict(probe.counters), peak=cell["peaks"]["devices"][device_kind],
         flops=flops, lib=trace, ops=trace.device_events(tr, "ops"),
-        modules=trace.device_events(tr, "modules"), config=cell["config"],
+        modules=trace.device_events(tr, "modules"), config=cell["config"], reference=reference,
     )
     metrics = {}
     for m in cell["per_layer"]:
@@ -404,7 +406,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace_on: bool, *,
     extra = {}
     if trace_on:
         metrics, bd, busy_s, traced_s, info.trace = per_layer(
-            cell, probe, win, dev.device_kind, len(times))
+            cell, probe, win, dev.device_kind, len(times), ref_mod)
         device.update(busy_s=busy_s, window_s=traced_s)
         extra = {"breakdown": bd}
         shutil.rmtree(win.logdir, ignore_errors=True)

@@ -102,9 +102,15 @@ def test_task_matches_the_configuration(cell):
     ref = run._module(c["config_dir"] / c["config"]["reference"])
     task = traffic.make_task(c["config"], ref.init_from_key)
     assert task.init_fn is ref.init_from_key
-    wrong = dict(c["config"], layers=dict(c["config"]["layers"], fc1=[1568, 64]))
-    with pytest.raises(ValueError):
-        traffic.make_task(wrong, ref.init_from_key)
+    layers = c["config"]["layers"]
+    missing = {k: v for k, v in layers.items() if not k.startswith("fc2/")}
+    for wrong in (dict(layers, **{"fc1/w": [1568, 64]}), missing,
+                  dict(layers, **{"fc3/w": [10, 10]}), dict(layers, **{"fc1/b": [64]})):
+        with pytest.raises(ValueError):
+            traffic.make_task(dict(c["config"], layers=wrong), ref.init_from_key)
+    for key, value in (("params", 206921), ("dtype", "bfloat16")):
+        with pytest.raises(ValueError):
+            traffic.make_task(dict(c["config"], **{key: value}), ref.init_from_key)
 
 
 def test_config_files_are_distinct_and_under_paths():
@@ -130,9 +136,9 @@ def test_traffic_sizes(cell, points):
         assert len(set(seeds)) == (1 if c["traffic"]["point_seeds"] == "shared" else points)
     else:
         assert t.selected == points
-        images, labels = t.shard(999_999)
-        assert images.shape == (c["config"]["examples_per_client"], 28, 28, 1)
-        assert labels.shape == (c["config"]["examples_per_client"],)
+        shard = t.shard(999_999)
+        assert shard["images"].shape == (c["config"]["examples_per_client"], 28, 28, 1)
+        assert shard["labels"].shape == (c["config"]["examples_per_client"],)
 
 
 def test_split_metric_names_share_a_reading():

@@ -82,13 +82,37 @@ def test_idle_split_by_innermost_host_span():
     assert dict(idle) == {"a": 35, "b": 5, "c": 5, "other": 30}
 
 
-def test_cnn_flops_by_hand():
+def _module(path):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(path.stem.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _reference(config):
+    cfg = json.loads((BENCH / "configs" / f"{config}.json").read_text())
+    return cfg, _module(BENCH / "configs" / cfg["reference"])
+
+
+@pytest.mark.parametrize("config", ["paper_testbed", "cross_device"])
+def test_cnn_flops_by_hand(config):
+    """The CNN's FLOPs by hand, as each CNN configuration's reference gives
+    them per example, and ``round_mfu`` for fixed counters exactly as the
+    count before the reference gave it: 2,436,096 x (3 x trained + eval)."""
     assert flops.CONV1_FLOPS == 225_792
     assert flops.CONV2_FLOPS == 1_806_336
     assert flops.FC1_FLOPS == 401_408
     assert flops.FC2_FLOPS == 2_560
     assert flops.CNN_FORWARD_FLOPS == 2_436_096
-    assert flops.cnn_flops(10, 400) == 2_436_096 * (30 + 400)
+    cfg, ref = _reference(config)
+    assert ref.flops_per_example(cfg) == {"train": 3 * 2_436_096, "eval": 2_436_096}
+    ctx = SimpleNamespace(counters={"fit_row_steps": 437.0, "eval_examples": 7600.0},
+                          window_s=5.0123456, peak=PEAK, config=cfg, reference=ref)
+    trained = 437 * cfg["batch_size"]
+    want = 100.0 * (2_436_096 * (3 * trained + 7600)) / (5.0123456 * PEAK["bf16_flops_per_s"])
+    assert _module(BENCH / "metrics" / "round_mfu.py").read(ctx) == want
 
 
 def test_kernel_bytes_and_roofline():
@@ -101,8 +125,7 @@ def test_kernel_bytes_and_roofline():
 
 
 def test_metric_readers_on_the_trace(tr):
-    import importlib.util
-
+    cfg, ref = _reference("paper_testbed")
     lo, hi = trace.window(tr)
     busy_s, window_s = trace.device_busy(tr)
     ctx = SimpleNamespace(
@@ -110,13 +133,10 @@ def test_metric_readers_on_the_trace(tr):
         counters={"fit_dispatches": 28, "select_s": 0.004, "fit_row_steps": 400,
                   "eval_examples": 800, "compiles": 0, "fedavg_reduce_bytes": 0},
         peak=PEAK, flops=flops, lib=trace, ops=trace.device_events(tr, "ops"),
-        modules=trace.device_events(tr, "modules"), config={"batch_size": 32})
+        modules=trace.device_events(tr, "modules"), config=cfg, reference=ref)
 
     def read(name):
-        spec = importlib.util.spec_from_file_location(name, BENCH / "metrics" / f"{name}.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read(ctx)
+        return _module(BENCH / "metrics" / f"{name}.py").read(ctx)
 
     assert read("device.idle_pct") == pytest.approx(100 * (1 - busy_s / window_s))
     fit_ns = trace.time_by_name(ctx.modules, r"jit_(fit_fused|run_chunk|init_state|finalize)\b", lo, hi)
@@ -124,6 +144,6 @@ def test_metric_readers_on_the_trace(tr):
     assert read("fit.dispatches_per_round") == 14
     assert read("host.select_ms_per_round") == pytest.approx(2.0)
     assert read("round_mfu") == pytest.approx(
-        100 * flops.cnn_flops(400 * 32, 800) / (window_s * PEAK["bf16_flops_per_s"]))
+        100 * 2_436_096 * (3 * 400 * 32 + 800) / (window_s * PEAK["bf16_flops_per_s"]))
     assert read("fedavg_reduce_roofline") is None  # no bytes, no share
     assert read("jit.compiles_in_window") == 0
