@@ -21,7 +21,13 @@ each worst over the sample:
 - ``change_gap``: the same for the change over the rounds followed.
 
 Leaves whose reference update is under a thousandth of the median leaf's
-are left out of both norm gaps (their change is round-off alone).
+are left out of both norm gaps (their change is round-off alone). Both
+sides are compared by their per-leaf norms against the point's start
+(``leaf_norms``), taken where each tree is made: the program's as the probe
+captures a round, the reference's as ``replay_norms`` reads its rounds. So
+what the check keeps does not grow with the payload's rounds or points:
+whole trees only where a population window sample starts from the
+program's params (``probe.TreeBuffer``).
 
 Transport: every round's outcomes as the engine received them, against
 the flow reference (``configs/<flow_reference>``), which gives for each
@@ -45,9 +51,11 @@ completes within the deadline, and the mean and variance of its duration:
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 NAMES = ("cohort_mismatches", "loss_gap", "loss_gap_nats", "update_gap", "change_gap")  # FL reference
@@ -55,27 +63,43 @@ TINY_LEAF = 1e-3
 TIME_FLOOR = 0.01  # durations agree to 1% where neither side varies
 
 
-def _leaf_norms(params, base) -> np.ndarray:
-    return np.array([
-        float(np.linalg.norm(np.asarray(a, np.float64) - np.asarray(b, np.float64)))
-        for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(base))
-    ])
+def _tree_norms(tree, base):
+    return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32) - b.astype(jnp.float32))))
+                      for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(base))])
 
 
-def norm_gap(prog, ref, base, keep: np.ndarray) -> float:
-    p, r = _leaf_norms(prog, base), _leaf_norms(ref, base)
+leaf_norms = jax.jit(_tree_norms)  # per leaf, ||tree - base||, on the device
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def init_norms(init_fn, tree, key):
+    """``leaf_norms`` against ``init_fn(key)``, made inside the reduction
+    rather than held."""
+    return _tree_norms(tree, init_fn(key))
+
+
+def replay_norms(replayed: List[Dict], base) -> List[Dict]:
+    """A reference replay's rounds with each round's per-leaf ``norms``
+    against ``base``, the replay's start: the replay's own where it gives
+    them, else those of its ``params``, which are not kept."""
+    return [{"cohort": x["cohort"], "loss": x["loss"], "norms": np.asarray(
+        x["norms"] if "norms" in x else leaf_norms(x["params"], base), np.float64)}
+        for x in replayed]
+
+
+def norm_gap(p: np.ndarray, r: np.ndarray, keep: np.ndarray) -> float:
     denom = np.maximum(r, np.median(r))
     if not np.all(denom[keep] > 0):
         return float("inf")
     return float(np.max(np.abs(p - r)[keep] / denom[keep]))
 
 
-def compare(prog: Dict, ref: List[Dict], init, committed: List[bool]) -> Dict[str, float]:
+def compare(prog: Dict, ref: List[Dict], committed: List[bool]) -> Dict[str, float]:
     """Readings of one point. ``prog`` holds per round followed
     ``cohorts[i]``, ``losses[i]`` (None where the round failed) and
-    ``params[i]`` (the global params after it); ``ref`` is
-    ``fl_reference.replay``'s output (or a control's) over the same rounds;
-    ``init`` the params before the first of them."""
+    ``norms[i]`` (per-leaf norms of the global params after it against the
+    params before the first round followed); ``ref`` is ``replay_norms``
+    over the reference's (or a control's) replay of the same rounds."""
     rounds = len(ref)
     mism = sum(1 for r in range(rounds) if list(prog["cohorts"][r]) != ref[r]["cohort"])
     nats = [abs(prog["losses"][r] - ref[r]["loss"]) for r in range(rounds) if committed[r]]
@@ -84,15 +108,15 @@ def compare(prog: Dict, ref: List[Dict], init, committed: List[bool]) -> Dict[st
     first = next((r for r in range(rounds) if committed[r]), None)
     if first is None:
         return dict({k: float("inf") for k in NAMES}, cohort_mismatches=float(mism))
-    r_first = _leaf_norms(ref[first]["params"], init)
+    r_first = ref[first]["norms"]
     keep = r_first >= TINY_LEAF * np.median(r_first)
     last = rounds - 1
     return {
         "cohort_mismatches": float(mism),
         "loss_gap": float(max(gaps)),
         "loss_gap_nats": float(max(nats)),
-        "update_gap": norm_gap(prog["params"][first], ref[first]["params"], init, keep),
-        "change_gap": norm_gap(prog["params"][last], ref[last]["params"], init, keep),
+        "update_gap": norm_gap(prog["norms"][first], r_first, keep),
+        "change_gap": norm_gap(prog["norms"][last], ref[last]["norms"], keep),
     }
 
 
@@ -114,22 +138,25 @@ def describe(checks: Dict[str, Dict]) -> List[str]:
     return [f"{k}: {c['value']!r} (limit {c['limit']!r})" for k, c in checks.items()]
 
 
-def program_point(history, captured: Dict[int, object], init, rounds: List[int]) -> Optional[Dict]:
+def program_point(history, norms: Dict[int, object], rounds: List[int],
+                  n_leaves: int) -> Optional[Dict]:
     """The program's side of one point over ``rounds``, from its History
-    and the params the probe kept; params of a failed round are those of
-    the round before. ``init``: the params before ``rounds[0]``."""
+    and the per-leaf norms the probe kept, against the params before
+    ``rounds[0]``: a failed round's are those of the round before, and
+    before the first round followed they are zero."""
     recs = {r.round_idx: r for r in history.rounds}
     if any(r not in recs for r in rounds):
         return None
     losses = {m["round"]: m["loss"] for m in history.eval_metrics}
-    params, prev = [], init
+    out, prev = [], np.zeros(n_leaves)
     for r in rounds:
-        prev = captured.get(r, prev)
-        params.append(prev)
+        if r in norms:
+            prev = np.asarray(norms[r], np.float64)
+        out.append(prev)
     return {
         "cohorts": [recs[r].selected_ids for r in rounds],
         "losses": [losses.get(r) for r in rounds],
-        "params": params,
+        "norms": out,
         "committed": [not recs[r].failed_round for r in rounds],
     }
 

@@ -9,8 +9,17 @@ round seam alone:
   too), and the end of each engine run;
 - what the references need: each round's transport outcomes as
   ``finish_transport`` receives them (cohort, connection state, completed,
-  arrival times) with the clients the round then committed, and the
-  global params after the rounds the FL reference follows.
+  arrival times) with the clients the round then committed, and, for the
+  rounds the FL reference follows from a point's start, the per-leaf norms
+  of the global params' change since that start (one jitted reduction
+  dispatched as ``finish_round`` returns; the start is remade inside it).
+  Where a window sample is followed from the program's own params
+  (``TreeBuffer``), host copies of the last few trees besides.
+
+Beyond the program's own arrays the probe holds at most one whole tree
+on the device (a copy to the host in flight) and ``TreeBuffer.size`` on
+the host, whatever the number of points, rounds or sweeps; ``kept`` says
+how many bytes it held at most of each.
 
 A traced run installs besides:
 
@@ -35,19 +44,62 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from harness import check
 from harness.data import examples
 
 SPAN_PREFIX = "bench."
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-class Probe:
-    """``capture_rounds``: the rounds whose params to keep, None for all."""
+def tree_bytes(tree) -> int:
+    return sum(int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize for l in jax.tree.leaves(tree))
 
-    def __init__(self, task, *, trace: bool, capture_rounds: Optional[set]):
+
+class TreeBuffer:
+    """Host copies of the trees after the last ``size`` rounds captured, up
+    to round ``until`` once it is set: where a window sample starts and
+    what it reaches, also where the window's end cuts it short. Each tree
+    starts its copy to the host as it is captured, and its device reference
+    is dropped at the next capture (or ``settle``), by when the copy is
+    done: no host sync inside a round."""
+
+    def __init__(self, size: int):
+        self.size = size
+        self.until: Optional[int] = None
+        self.trees: Dict[int, Any] = {}  # round -> numpy tree
+        self._pending: Optional[tuple] = None  # (round, device tree)
+        self.device_bytes = self.host_bytes = 0  # the most held at once
+
+    def add(self, rnd: int, tree) -> None:
+        if self.until is not None and rnd > self.until:
+            return
+        self.settle()
+        for leaf in jax.tree.leaves(tree):
+            leaf.copy_to_host_async()
+        self._pending = (rnd, tree)
+        self.device_bytes = max(self.device_bytes, tree_bytes(tree))
+
+    def settle(self) -> None:
+        if self._pending is None:
+            return
+        rnd, tree = self._pending
+        self._pending = None
+        self.trees[rnd] = jax.tree.map(np.asarray, tree)
+        while len(self.trees) > self.size:
+            del self.trees[min(self.trees)]
+        self.host_bytes = max(self.host_bytes, sum(map(tree_bytes, self.trees.values())))
+
+
+class Probe:
+    """``capture_rounds``: the rounds whose norms to keep."""
+
+    def __init__(self, task, *, trace: bool, capture_rounds: set):
         self.task = task
         self.trace = trace
         self.capture_rounds = capture_rounds
+        shapes = jax.eval_shape(task.init_fn, jax.random.PRNGKey(0))
+        self.n_leaves = len(jax.tree.leaves(shapes))
+        self.tree_bytes = tree_bytes(shapes)
         self.counting = False  # counters advance inside a measured window only
         self.counters: Dict[str, float] = defaultdict(float)
         self.run_id: Any = None
@@ -56,8 +108,10 @@ class Probe:
         self.run_ends: Dict[Any, float] = {}
         # id(ServerConfig) -> one record per round that reached transport
         self.flows: Dict[int, List[Dict[str, Any]]] = defaultdict(list)
+        # id(ServerConfig) -> round -> per-leaf norms against the point's start
         self.captured: Dict[int, Dict[int, Any]] = defaultdict(dict)
-        self.capture_keys: set = set()  # id(ServerConfig) whose params to keep
+        self.capture_keys: set = set()  # id(ServerConfig) whose rounds to capture
+        self.buffers: Dict[int, TreeBuffer] = {}  # id(ServerConfig) -> its trees
         self.on_begin_round: Optional[Callable] = None
         self.deadline: Optional[float] = None  # the window closes at its first round after this
         self.closed = False
@@ -86,6 +140,24 @@ class Probe:
 
     def fit_dispatches(self) -> int:
         return len(self.task.fit_rows.runner.dispatch_widths)
+
+    # -- what the references compare ---------------------------------------------
+    def capture(self, srv, rnd: int) -> None:
+        key = id(srv.config)
+        if rnd in self.capture_rounds:
+            self.captured[key][rnd] = check.init_norms(
+                self.task.init_fn, srv.global_params, jax.random.PRNGKey(srv.config.seed))
+        if key in self.buffers:
+            self.buffers[key].add(rnd, srv.global_params)
+
+    def kept(self) -> Dict[str, int]:
+        """The most bytes of whole trees the probe held at once, on the
+        device and on the host, and the bytes of one tree."""
+        for buf in self.buffers.values():
+            buf.settle()
+        return {"device": max((b.device_bytes for b in self.buffers.values()), default=0),
+                "host": max((b.host_bytes for b in self.buffers.values()), default=0),
+                "tree": self.tree_bytes}
 
     # -- rounds ---------------------------------------------------------------
     def mark_round(self, rnd: int) -> None:
@@ -176,10 +248,8 @@ class Probe:
             def f(srv, job, *a, **kw):
                 with probe.span("finish_round"):
                     out = orig(srv, job, *a, **kw)
-                key = id(srv.config)
-                if key in probe.capture_keys and (
-                        probe.capture_rounds is None or job.rnd in probe.capture_rounds):
-                    probe.captured[key][job.rnd] = srv.global_params
+                if id(srv.config) in probe.capture_keys:
+                    probe.capture(srv, job.rnd)
                 return out
             return f
 

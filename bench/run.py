@@ -141,6 +141,9 @@ class Window:
         if self.trace:
             self._ann.__exit__(None, None, None)
             jax.profiler.stop_trace()
+        kept = probe.kept()
+        print(f"bench: the check kept at most {kept['device']} B of whole trees on the device "
+              f"and {kept['host']} B on the host (a tree is {kept['tree']} B)", file=sys.stderr)
 
     @property
     def seconds(self) -> float:
@@ -149,8 +152,6 @@ class Window:
 
 def run_grid(traffic, task, probe, seconds: float, win: Window, seed: int):
     """Set-up: sweep 0. Window: sweeps 1, 2, ... until ``seconds`` pass."""
-    import jax
-
     from harness import check as chk
 
     rng = _draws(seed, 1)
@@ -214,9 +215,8 @@ def run_grid(traffic, task, probe, seconds: float, win: Window, seed: int):
         point = dict(traffic.reference_point(s), delivered={
             r["rnd"]: r["committed"] for r in probe.flows[key] if r["run"] == k})
         rounds = list(range(CHECK_ROUNDS))
-        base = task.init_fn(jax.random.PRNGKey(s))
         out.samples.append((point, rounds, None,
-                            chk.program_point(h, probe.captured[key], base, rounds)))
+                            chk.program_point(h, probe.captured[key], rounds, probe.n_leaves)))
     return out
 
 
@@ -225,13 +225,16 @@ def run_population(traffic, task, probe, seconds: float, win: Window, seed: int)
     the window continues the same ``run()`` until ``seconds`` pass. The FL
     reference follows rounds 0-2 and three consecutive window rounds drawn
     from the seed, from the program's params before them."""
-    import jax
-
     from harness import check as chk
+    from harness.probe import TreeBuffer
 
     server = traffic.server(task)
     key = id(server.config)
     probe.capture_keys.add(key)
+    buf = probe.buffers[key] = TreeBuffer(CHECK_ROUNDS + 1)
+    # drawn from the seed alone, within the rounds a full window holds,
+    # so that a seed follows the same rounds in every run
+    offset = int(_draws(seed, 2).integers(0, traffic.traffic["window_sample_rounds"]))
     out = SimpleNamespace(client_rounds=0, failed=0, runs=["window"], error=None)
     state = {"phase": "setup", "first": None}
     warm = traffic.traffic["warm_rounds"]
@@ -240,6 +243,7 @@ def run_population(traffic, task, probe, seconds: float, win: Window, seed: int)
         if state["phase"] == "setup" and srv.history.completed_rounds >= warm:
             out.setup_s = time.perf_counter() - T_START
             state.update(phase="window", first=rnd)
+            buf.until = rnd + offset + CHECK_ROUNDS - 1
             probe.run_id = "window"
             win.open(probe, seconds)
 
@@ -263,23 +267,23 @@ def run_population(traffic, task, probe, seconds: float, win: Window, seed: int)
 
     point = dict(traffic.reference_point(),
                  delivered={r["rnd"]: r["committed"] for r in probe.flows[key]})
-    captured = probe.captured[key]
-    init = task.init_fn(jax.random.PRNGKey(point["seed"]))
     rounds = list(range(CHECK_ROUNDS))
-    out.samples = [(point, rounds, None, chk.program_point(h, captured, init, rounds))]
+    out.samples = [(point, rounds, None,
+                    chk.program_point(h, probe.captured[key], rounds, probe.n_leaves))]
     last = max((r.round_idx for r in window_rounds), default=-1)
+    del server
     if first is not None and last - CHECK_ROUNDS + 1 >= first:
-        # drawn from the seed alone, within the rounds a full window holds,
-        # so that a seed follows the same rounds in every run
-        offset = int(_draws(seed, 2).integers(0, traffic.traffic["window_sample_rounds"]))
         r0 = min(first + offset, last - CHECK_ROUNDS + 1)
         rounds = list(range(r0, r0 + CHECK_ROUNDS))
-        before = [r for r in captured if r < r0]
-        base = captured[max(before)] if before else init
-        out.samples.append((point, rounds, base, chk.program_point(h, captured, base, rounds)))
+        # the tree after the last round captured before r0 (set-up committed
+        # rounds, and the buffer holds a round before any 3 that follow it)
+        base = buf.trees[max(r for r in buf.trees if r < r0)]
+        norms = {r: chk.leaf_norms(buf.trees[r], base) for r in rounds if r in buf.trees}
+        out.samples.append((point, rounds, base,
+                            chk.program_point(h, norms, rounds, probe.n_leaves)))
     else:  # the window held too few rounds to follow: nothing to compare
         out.samples.append((point, [], None, None))
-    del server
+    buf.trees.clear()
     return out
 
 
@@ -302,11 +306,11 @@ def check(cell: Dict, run, ref_mod, *, control: bool = False,
             readings.append({k: float("inf") for k in chk.NAMES})
             continue
         base = ref_mod.init_params(point["seed"]) if start is None else start
-        ref = ref_mod.replay(point, rounds, start=start, **hp)
+        ref = chk.replay_norms(ref_mod.replay(point, rounds, start=start, **hp), base)
         if control:
-            lo = ref_mod.replay(point, rounds, start=start, dtype=low, **hp)
-            prog = dict(prog, losses=[x["loss"] for x in lo], params=[x["params"] for x in lo])
-        readings.append(chk.compare(prog, ref, base, prog["committed"]))
+            lo = chk.replay_norms(ref_mod.replay(point, rounds, start=start, dtype=low, **hp), base)
+            prog = dict(prog, losses=[x["loss"] for x in lo], norms=[x["norms"] for x in lo])
+        readings.append(chk.compare(prog, ref, prog["committed"]))
         if detail is not None:
             detail.append({"rounds": rounds, "losses": prog["losses"],
                            "ref_losses": [x["loss"] for x in ref], **readings[-1]})
@@ -384,8 +388,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, trace_on: bool, *,
     traffic = traffic_mod.build(cfg, cell["traffic"], seed)
     task = traffic_mod.make_task(cfg, ref_mod.init_from_key)
     grid = traffic.engine == "grid"
-    probe = probe_mod.Probe(task, trace=trace_on,
-                            capture_rounds=set(range(CHECK_ROUNDS)) if grid else None)
+    probe = probe_mod.Probe(task, trace=trace_on, capture_rounds=set(range(CHECK_ROUNDS)))
     probe.install()
     win = Window(trace_on)
     window_s = min(seconds, TRACE_SECONDS) if trace_on else seconds

@@ -107,16 +107,19 @@ def test_delivery_numbers_by_hand():
 
 
 def test_loss_gaps_by_hand():
-    """The relative gap grows as the loss falls; the gap in nats does not."""
+    """The relative gap grows as the loss falls; the gap in nats does not.
+    A replay's round gives its params or its norms against the start."""
     from harness import check
 
     base = {"w": np.zeros(3, np.float32)}
     step = {"w": np.ones(3, np.float32)}
-    ref = [{"cohort": [1], "params": step, "loss": 2.0}, {"cohort": [1], "params": step, "loss": 0.2}]
-    prog = {"cohorts": [[1], [1]], "losses": [2.001, 0.201], "params": [step, step]}
-    out = check.compare(prog, ref, base, [True, True])
+    ref = check.replay_norms([{"cohort": [1], "params": step, "loss": 2.0},
+                              {"cohort": [1], "norms": np.array([3 ** 0.5]), "loss": 0.2}], base)
+    assert [list(r["norms"]) for r in ref] == [pytest.approx([3 ** 0.5])] * 2
+    prog = {"cohorts": [[1], [1]], "losses": [2.001, 0.201], "norms": [r["norms"] for r in ref]}
+    out = check.compare(prog, ref, [True, True])
     assert out["loss_gap_nats"] == pytest.approx(0.001, rel=1e-6)
     assert out["loss_gap"] == pytest.approx(0.005, rel=1e-6)
     assert out["update_gap"] == 0.0 and out["change_gap"] == 0.0
-    failed = check.compare(prog, ref, base, [False, False])
+    failed = check.compare(prog, ref, [False, False])
     assert all(np.isinf(failed[k]) for k in check.NAMES if k != "cohort_mismatches")

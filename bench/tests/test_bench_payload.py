@@ -122,7 +122,7 @@ def test_probe_counts_eval_examples_by_leading_axis(config, fixture_cell):
     cfg = cell["config"]
     task = traffic.make_task(cfg, ref.init_from_key)
     t = traffic.build(cfg, cell["traffic"], SEED)
-    probe = probe_mod.Probe(task, trace=True, capture_rounds=None)
+    probe = probe_mod.Probe(task, trace=True, capture_rounds=set())
     probe.install()
     try:
         probe.counting = True
