@@ -110,6 +110,7 @@ class GridStats:
     transport_dispatches: int = 0  # hoisted host sim_grid_round calls
     transport_device_dispatches: int = 0  # hoisted device-plane programs
     transport_rows: int = 0  # (point, client) rows sampled through them
+    gather_dispatches: int = 0  # row-gather programs: (point, round) pairs with rows
     async_flushes: int = 0  # async buffer flushes across all points
     # fault-domain observability: points retired by quarantine, rounds
     # lost to server_restart chaos events, and crash-consistency telemetry
@@ -126,30 +127,53 @@ class GridResult:
     servers: List[FederatedServer]  # post-run per-point state (inspection)
 
 
+@jax.jit
+def _gather_program(planes, idx):
+    """Rows ``idx`` [D] int32 of the concatenation of ``planes`` (a tuple of
+    chunk plane trees), as one program. Each chunk gathers every row with
+    its index clamped into range, and a select keeps the rows that lie in
+    it: exact bits, in ``idx`` order, with no concatenated plane. Compiles
+    per (chunk widths, D)."""
+    out, lo = None, 0
+    for plane in planes:
+        width = jax.tree.leaves(plane)[0].shape[0]
+        local = jnp.clip(idx - lo, 0, width - 1)
+        rows = jax.tree.map(lambda l: l.at[local].get(mode="promise_in_bounds"), plane)
+        if out is None:
+            out = rows
+        else:  # a row at or past this chunk's start lies in it or a later one
+            mine = idx >= lo
+            out = jax.tree.map(
+                lambda a, b: jnp.where(mine.reshape((-1,) + (1,) * (a.ndim - 1)), b, a),
+                out, rows,
+            )
+        lo += width
+    return out
+
+
+def _segments(idxs: List[int], chunk: int) -> int:
+    """Runs of consecutive ``idxs`` that lie in one chunk."""
+    return 1 + sum(a // chunk != b // chunk for a, b in zip(idxs, idxs[1:]))
+
+
 def _gather_rows(planes, chunk: int, idxs: List[int]):
     """Collect plane rows ``idxs`` (global row numbers, delivery order)
     from per-chunk plane outputs. Returns (stacked [D,...], n_ex, metrics).
 
     Row order is preserved exactly: aggregation reduces over the client
     axis, so the stacked deltas must line up with the per-point engine's
-    delivery order for bit-identical weighted means."""
-    segments: List[List[int]] = [[idxs[0]]]
-    for k in idxs[1:]:
-        if k // chunk == segments[-1][-1] // chunk:
-            segments[-1].append(k)
-        else:
-            segments.append([k])
-    trees, n_out, m_out = [], [], []
-    for seg in segments:
-        ci = seg[0] // chunk
-        plane, n_ex, mets = planes[ci]
-        lis = [k - ci * chunk for k in seg]
-        trees.append(jax.tree.map(lambda l: l[np.asarray(lis)], plane))
-        n_out += [n_ex[li] for li in lis]
-        m_out += [mets[li] for li in lis]
-    if len(trees) == 1:
-        return trees[0], n_out, m_out
-    stacked = jax.tree.map(lambda *ls: jnp.concatenate(ls, axis=0), *trees)
+    delivery order for bit-identical weighted means. The chunks the rows
+    touch go to one jitted program with one int32 index into their
+    concatenation; ``n_ex`` and ``metrics`` are picked on the host."""
+    touched = sorted({k // chunk for k in idxs})
+    base, at = {}, 0
+    for c in touched:
+        base[c] = at - c * chunk
+        at += jax.tree.leaves(planes[c][0])[0].shape[0]
+    idx = np.asarray([k + base[k // chunk] for k in idxs], np.int32)
+    stacked = _gather_program(tuple(planes[c][0] for c in touched), idx)
+    n_out = [planes[k // chunk][1][k % chunk] for k in idxs]
+    m_out = [planes[k // chunk][2][k % chunk] for k in idxs]
     return stacked, n_out, m_out
 
 
@@ -642,10 +666,12 @@ def run_fl_grid(
         for i, job, gkey, idxs, row_keys in placements:
             srv = servers[i]
             if idxs:
-                with span("gather_rows", rows=len(idxs)):
+                with span("gather_rows", rows=len(idxs),
+                          segments=_segments(idxs, max_plane_rows)):
                     stacked, weights, per_metrics = _gather_rows(
                         groups[gkey]["planes"], max_plane_rows, idxs
                     )
+                stats.gather_dispatches += 1
             else:  # async drain-only tick: no rows were placed
                 stacked, weights, per_metrics = None, [], []
             # fault domain first, BEFORE the shared compression pass can

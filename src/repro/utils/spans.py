@@ -21,7 +21,7 @@ Open a run inside ``jax.profiler.trace(logdir)`` and read these in Perfetto
 - ``fl.fit.h2d`` [bytes]: the copy of that block's examples to the device.
 - ``fl.fit.anchors`` [anchors]: the host build of one block's anchor table (its distinct anchors), row index and prox arrays.
 - ``fl.fit.dispatch`` [rows, steps]: the fit program's call for one block.
-- ``fl.gather_rows`` [rows]: the grid's gather of one point's rows from the fit planes.
+- ``fl.gather_rows`` [rows, segments]: the grid's gather of one point's rows from the fit planes, one jitted program each (``GridStats.gather_dispatches`` counts them); ``segments``: runs of rows from one plane chunk, above 1 where the rows span chunks.
 - ``fl.divergence``: ``FederatedServer._divergence_cause``, the quarantine check.
 - ``fl.aggregate`` [rows]: ``Strategy.aggregate_stacked``.
 - ``fl.finish_round`` [rows]: ``FederatedServer.finish_round``: bookkeeping, aggregation, eval.

@@ -291,6 +291,120 @@ def test_anchor_table_keeps_one_fit_program():
 
 
 # ---------------------------------------------------------------------------
+# per-point row gather: one program, bitwise the eager per-leaf gather
+# ---------------------------------------------------------------------------
+
+
+def _eager_gather_rows(planes, chunk, idxs):
+    """The per-leaf eager gather the grid ran before its one-program
+    gather: one numpy-indexed take per leaf and segment, then an eager
+    concatenate across segments."""
+    segments = [[idxs[0]]]
+    for k in idxs[1:]:
+        if k // chunk == segments[-1][-1] // chunk:
+            segments[-1].append(k)
+        else:
+            segments.append([k])
+    trees, n_out, m_out = [], [], []
+    for seg in segments:
+        ci = seg[0] // chunk
+        plane, n_ex, mets = planes[ci]
+        lis = [k - ci * chunk for k in seg]
+        trees.append(jax.tree.map(lambda l: l[np.asarray(lis)], plane))
+        n_out += [n_ex[li] for li in lis]
+        m_out += [mets[li] for li in lis]
+    if len(trees) == 1:
+        return trees[0], n_out, m_out
+    return jax.tree.map(lambda *ls: jnp.concatenate(ls, axis=0), *trees), n_out, m_out
+
+
+def _chunk_planes(template, chunk, n_rows, seed):
+    """Per-chunk (plane, n_ex, metrics) triples of ``n_rows`` rows in
+    chunks of ``chunk``, each plane padded to ``bucket_rows`` like the fit
+    planes; leaves carry the ``template`` tree's shapes."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for s in range(0, n_rows, chunk):
+        n = min(chunk, n_rows - s)
+        plane = jax.tree.map(
+            lambda l: jnp.asarray(
+                rng.standard_normal((bucket_rows(n),) + l.shape).astype(l.dtype)
+            ),
+            template,
+        )
+        out.append((plane, [s + i for i in range(n)],
+                    [{"loss": float(s + i)} for i in range(n)]))
+    return out
+
+
+@pytest.mark.parametrize(
+    "chunk, n_rows, idxs",
+    [
+        (64, 64, [3, 7, 9, 20, 41]),  # one segment
+        (64, 112, [60, 61, 62, 63, 64, 65, 70]),  # straddles two 64-row chunks
+        (16, 40, [14, 15, 16, 20, 31, 32, 35]),  # three chunks
+        (64, 112, [70]),  # D = 1
+        (64, 112, [3, 3, 7, 7, 7, 64, 64]),  # coalesced rows repeat
+        (64, 112, [2, 66, 5, 70, 1]),  # leaves chunk 0 and comes back
+    ],
+    ids=["one_segment", "two_chunks", "three_chunks", "d1", "duplicates", "non_monotone"],
+)
+def test_gather_rows_matches_eager_gather(chunk, n_rows, idxs):
+    """The one-program gather returns, bitwise and in delivery order, the
+    rows the eager per-leaf gather returns, with the same host lists."""
+    from repro.core.grid import _gather_rows
+
+    planes = _chunk_planes(TASK.init_fn(jax.random.PRNGKey(0)), chunk, n_rows, seed=len(idxs))
+    got, n_ex, mets = _gather_rows(planes, chunk, idxs)
+    ref, ref_n_ex, ref_mets = _eager_gather_rows(planes, chunk, idxs)
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert a.tobytes() == b.tobytes()
+    assert n_ex == ref_n_ex == idxs
+    assert mets == ref_mets
+
+
+def test_gather_rows_is_one_program():
+    """A point's gather is one compiled program, also across chunks, and a
+    repeat with the same shapes compiles nothing. In a grid run,
+    GridStats.gather_dispatches counts one gather per (point, round) pair
+    whose round placed rows."""
+    from repro.core.grid import _gather_rows
+
+    compiled = []
+
+    def on_event(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(kw.get("fun_name"))
+
+    # leaf shapes no other test gathers, so the first call compiles
+    template = {"a": jnp.zeros((3, 5)), "b": {"c": jnp.zeros((7,))}}
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        _gather_rows(_chunk_planes(template, 64, 112, seed=1), 64, [60, 70, 2])
+        assert compiled == ["jit(_gather_program)"], compiled
+        compiled.clear()
+        _gather_rows(_chunk_planes(template, 64, 112, seed=2), 64, [1, 90, 64])
+        assert compiled == []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+    kwargs = [
+        dict(tcp=DEFAULT, link=LAB),
+        dict(tcp=DEFAULT, link=LAB.replace(loss=0.15)),
+        dict(tcp=DEFAULT, link=LAB.replace(delay=8.0)),  # no deliveries
+    ]
+    res = run_fl_grid(TASK, [_point(rounds=2, **kw) for kw in kwargs], eval_data=EVAL)
+    with_rows = sum(
+        1 for h in res.histories for r in h.rounds if r.delivered and not r.failed_round
+    )
+    assert 0 < with_rows < 2 * len(kwargs)
+    assert res.stats.gather_dispatches == with_rows
+
+
+# ---------------------------------------------------------------------------
 # (S, C) transport grid + sparse traces
 # ---------------------------------------------------------------------------
 

@@ -177,6 +177,19 @@ def test_grid_sync_count_per_round(grid_runs):
     assert traced.stats.evals_computed == trained_total
 
 
+def test_gather_rows_span_per_trained_point(grid_runs):
+    """One ``fl.gather_rows`` span per (point, round) that trained, carrying
+    its delivered rows and the chunk runs they cover (one: both points'
+    rows fit one ``max_plane_rows`` chunk); GridStats counts the same."""
+    _, traced, events = grid_runs
+    gathers = [e for e in events if e[0] == "fl.gather_rows"]
+    delivered = [h.rounds[r].delivered for r in range(2) for h in traced.histories
+                 if not h.rounds[r].failed_round]
+    assert [e[3]["rows"] for e in gathers] == delivered
+    assert {e[3]["segments"] for e in gathers} == {1}
+    assert traced.stats.gather_dispatches == len(gathers)
+
+
 def test_population_sync_count_per_round(population_runs):
     """Per committed round: transport, fit metrics, divergence, eval."""
     _, traced, events = population_runs
