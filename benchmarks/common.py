@@ -144,7 +144,6 @@ def _make_point(
     compressor=None,
     stochastic: bool = False,
     rng_streams: str = "single",
-    engine: str = "default",
     transport_backend: str = "host",
     retry: Optional[RetryPolicy] = None,
     client_links: Optional[List[Optional[LinkProfile]]] = None,
@@ -200,7 +199,7 @@ def _make_point(
         chaos=chaos or ChaosSchedule(link),
         config=ServerConfig(
             rounds=rounds, local_steps=local_steps, seed=seed, batched=batched,
-            stochastic=stochastic, rng_streams=rng_streams, engine=engine,
+            stochastic=stochastic, rng_streams=rng_streams,
             transport_backend=transport_backend, retry=retry,
             round_deadline=round_deadline,
             max_consecutive_failures=max_consecutive_failures,

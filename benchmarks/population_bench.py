@@ -5,7 +5,7 @@ Two sections, one BENCH json line each:
 1. **Parity gate** (always on, CI-enforced): at N=8 clients the sparse
    state plane must reproduce the dense plane BITWISE — every
    ``History.summary()`` field, every per-round record — across the
-   sequential / batched / fused_transport engines and the topk / int8 /
+   sequential / batched / split-stream stochastic engines and the topk / int8 /
    bf16 plane compressors, plus a lazy ``Population`` universe against
    the materialized list on identical shards.  Any drift fails the bench
    (SystemExit), which fails CI.
@@ -64,8 +64,8 @@ MEM_BUDGET_BYTES = 1024 * 1024 * 1024
 _PARITY_ENGINES = {
     "sequential": dict(batched=False),
     "batched": dict(batched=True),
-    "fused_transport": dict(batched=True, stochastic=True,
-                            engine="fused_transport"),
+    "split_streams": dict(batched=True, stochastic=True,
+                          rng_streams="split"),
 }
 _PARITY_COMPRESSORS = {
     "topk:0.1": lambda: topk_compressor(0.1),

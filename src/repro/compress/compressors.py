@@ -132,9 +132,9 @@ def _plane_compress_fn(row_fn):
 
     The residual plane is DONATED into the scatter program: XLA reuses its
     buffers instead of allocating a second model-times-clients copy per
-    round. The pieces are exposed as attributes on the returned function
-    (``gather_rows`` / ``compress_rows`` / ``scatter_rows`` /
-    ``finalize``) for callers that orchestrate sharing themselves.
+    round. Given a ``memo`` dict and a ``key`` (equal keys imply
+    bitwise-equal ``compress_rows`` results), the heavy pass runs once
+    per key and the memo holds its result; gather and scatter always run.
     """
 
     @jax.jit
@@ -163,17 +163,17 @@ def _plane_compress_fn(row_fn):
             lambda d, q2: q2.reshape(d.shape).astype(d.dtype), stacked, deq_tree
         )
 
-    def compress_plane(stacked, residual_plane, slots):
+    def compress_plane(stacked, residual_plane, slots, memo=None, key=None):
         slots = jnp.asarray(slots, jnp.int32)
-        rows = gather_rows(residual_plane, slots)
-        x2_tree, deq_tree = compress_rows(stacked, rows)
+        hit = None if memo is None else memo.get(key)
+        if hit is None:
+            hit = compress_rows(stacked, gather_rows(residual_plane, slots))
+            if memo is not None:
+                memo[key] = hit
+        x2_tree, deq_tree = hit
         new_res = scatter_rows(x2_tree, deq_tree, residual_plane, slots)
         return finalize(stacked, deq_tree), new_res
 
-    compress_plane.gather_rows = gather_rows
-    compress_plane.compress_rows = compress_rows
-    compress_plane.scatter_rows = scatter_rows
-    compress_plane.finalize = finalize
     return compress_plane
 
 

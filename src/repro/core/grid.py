@@ -51,6 +51,7 @@ reference 1-3 distinct anchors across a 64-row plane.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -66,6 +67,7 @@ from repro.core.server import (
     _GRID_STREAM,
     _GRID_ZR_STREAM,
     FederatedServer,
+    FitJob,
     History,
     PendingRound,
     ServerConfig,
@@ -341,6 +343,326 @@ def _check_checkpointable(servers: List[FederatedServer]) -> None:
             raise ValueError(f"point {i}: {e}") from None
 
 
+class _Provenance:
+    """A grid run's provenance keys, interned to ints, and the eval memo
+    keyed on them. ``params_keys[i]``: equal keys => bitwise-equal global
+    params (same init, same aggregation chain over the same rows).
+    ``res_keys[i]`` mirrors it for the compression error-feedback plane:
+    equal keys => bitwise-equal residual state (same compressor, same
+    chain of (rows, delivering slots) updates from zeros).
+
+    The servers' eval and async-flush hooks hold this object, never the
+    sweep: a hook that reached the servers would close a reference
+    cycle, and each finished sweep's servers (params on the device)
+    would then wait for the cyclic collector."""
+
+    def __init__(self, task: LocalTask, stats: GridStats):
+        self.task = task
+        self.stats = stats
+        self.nonce = itertools.count()
+        self.interned: Dict[Any, int] = {}
+        self.params_keys: List[int] = []
+        self.res_keys: List[int] = []
+        self.eval_cache: Dict[Tuple[int, int], Dict[str, float]] = {}
+
+    def intern(self, key) -> int:
+        return self.interned.setdefault(key, len(self.interned))
+
+    def opaque(self) -> int:
+        """A key equal to no other: the point's state shares with nobody."""
+        return self.intern(("opaque", next(self.nonce)))
+
+    def eval(self, i: int, params, data):
+        """Point i's eval, memoized on its params provenance."""
+        self.stats.evals_requested += 1
+        key = (self.params_keys[i], id(data))
+        hit = self.eval_cache.get(key)
+        if hit is None:
+            hit = self.eval_cache[key] = self.task.evaluate(params, data)
+            self.stats.evals_computed += 1
+        return dict(hit)  # finish_round annotates the dict in place
+
+    def async_flush(self, i: int, srv: FederatedServer, rnd: int) -> None:
+        """Advance point i's params provenance at buffer-flush time.
+
+        finish_round calls this right after ``_async_tick`` and BEFORE the
+        memoized eval, so the eval cache keys on the post-flush
+        trajectory. No flush => params unchanged => the key stands (and
+        drain-only ticks keep coalescing with their pre-tick twins). A
+        flush whose events all carry provenance tokens digests to
+        ("agg-async", prior key, aggregation identity, the (token,
+        staleness, weight) event tuple, alpha, round) — two async points
+        flushing identical events over identical trajectories keep
+        bitwise-equal params and shared eval."""
+        fl = srv._last_flush
+        if fl is None:
+            return
+        self.stats.async_flushes += 1
+        if fl["opaque"]:
+            self.params_keys[i] = self.opaque()
+        else:
+            self.params_keys[i] = self.intern((
+                "agg-async", self.params_keys[i], srv.strategy.agg_fingerprint,
+                fl["events"], float(srv.config.staleness_alpha), rnd,
+                bool(srv.config.batched),
+            ))
+
+
+class _Sweep:
+    """One grid run's lockstep state: the per-point servers, the
+    telemetry, and the provenance keys rows coalesce on (``prov``).
+    ``round`` drives one lockstep round through its phases, in order:
+    ``select_and_transport`` -> ``plan_rows`` -> ``dispatch`` -> ``post``."""
+
+    def __init__(self, task: LocalTask, points: Sequence[GridPoint], eval_data, *,
+                 coalesce: bool, max_plane_rows: int, transport: str,
+                 transport_seed: int):
+        self.task = task
+        self.coalesce = coalesce
+        self.max_plane_rows = max_plane_rows
+        self.transport = transport
+        self.transport_seed = transport_seed
+        self.stats = GridStats()
+        self.prov = prov = _Provenance(task, self.stats)
+        self.servers: List[FederatedServer] = []
+        for i, p in enumerate(points):
+            srv = FederatedServer(
+                task, p.clients, p.strategy, tcp=p.tcp, chaos=p.chaos,
+                config=p.config, compressor=p.compressor, eval_data=eval_data,
+                eval_fn=functools.partial(prov.eval, i),
+            )
+            if p.config.async_mode:
+                srv._async_prov_hook = functools.partial(prov.async_flush, i)
+            self.servers.append(srv)
+            prov.params_keys.append(prov.intern(("init", id(task), p.config.seed)))
+            prov.res_keys.append(prov.intern(("res0", srv.compressor.fingerprint)))
+        self.plane_ok = (
+            task.plan_fit is not None
+            and task.fit_rows is not None
+            and task.plan_digest is not None
+        )
+
+    def _hoistable(self, srv: FederatedServer) -> bool:
+        # the hoist reproduces the BATCHED cohort draw discipline, and a
+        # point's selection stream only survives it under the split-rng
+        # contract; everything else keeps sampling inside begin_round.
+        # Parity mode is defined as bitwise per-point reproduction, and a
+        # device-backend point's per-point reference is the device plane
+        # keyed on its own (seed, stream, round) — a hoisted numpy pass
+        # cannot reproduce it, so such points stay on their own path.
+        if self.transport == "per_point":
+            return False
+        if self.transport == "parity" and srv.config.transport_backend == "device":
+            return False
+        return srv.config.stochastic and srv.config.batched and srv.split_streams
+
+    def round(self, rnd: int) -> None:
+        jobs = self.select_and_transport(rnd)
+        groups, placements = self.plan_rows(jobs)
+        if placements:
+            self.dispatch(groups)
+            self.post(groups, placements)
+
+    def select_and_transport(self, rnd: int) -> List[Tuple[int, FitJob]]:
+        """Selection on each point's own RNG stream, in point order, with
+        transport sampled inline (``begin_round``) or deferred to ONE
+        shared plane call for the round. Returns (point, FitJob) pairs in
+        point order."""
+        jobs = []
+        waiting = []  # (point_idx, PendingRound) awaiting plane transport
+        for i, srv in enumerate(self.servers):
+            if srv.terminated or rnd >= srv.config.rounds:
+                continue
+            if not self._hoistable(srv):
+                job = srv.begin_round(rnd)
+            else:
+                pr = srv.select_cohort(rnd)
+                if pr is None:
+                    continue
+                if pr.cohort:
+                    waiting.append((i, pr))
+                    continue
+                # async drain-only tick: nothing to sample, the plane
+                # never sees it — the tick still drains its event queue
+                # through finish_round
+                z = np.zeros(0)
+                job = srv.finish_transport(pr, np.zeros(0, bool), z, z, z)
+            if job is not None:
+                jobs.append((i, job))
+        if waiting:
+            n_rows = sum(len(pr.cohort) for _, pr in waiting)
+            with span("transport", rows=n_rows):
+                outcomes = _plane_transport(
+                    waiting, self.servers, self.transport, self.transport_seed,
+                    rnd, self.stats,
+                )
+            self.stats.transport_rows += n_rows
+            for (i, pr), outcome in zip(waiting, outcomes):
+                job = self.servers[i].finish_transport(pr, *outcome)
+                if job is not None:
+                    jobs.append((i, job))
+            jobs.sort(key=lambda ij: ij[0])  # point order, deterministic
+        return jobs
+
+    def plan_rows(self, jobs: List[Tuple[int, FitJob]]):
+        """Plan each point's batches and place its rows in the row table:
+        rows equal in (params provenance, batch-plan digest, steps, mu)
+        coalesce into one. Points with no plane path fit and finish here.
+
+        Returns (groups, placements): groups keyed by the plane program's
+        static axes (steps, use_prox); placements (point, job, group key,
+        row idxs, interned row keys) in point order."""
+        prov = self.prov
+        pending = []  # (point_idx, FitJob, plans)
+        for i, job in jobs:
+            srv = self.servers[i]
+            if not (self.plane_ok and srv.config.batched):
+                stacked, deltas, weights, per_metrics = srv.execute_fit(job)
+                prov.params_keys[i] = prov.opaque()
+                prov.res_keys[i] = prov.opaque()
+                srv.finish_round(job, stacked, deltas, weights, per_metrics)
+                continue
+            with span("plan", rows=len(job.clients)):
+                plans = self.task.plan_fit(job.clients, job.steps, srv.rng)
+            pending.append((i, job, plans))
+        groups: Dict[tuple, dict] = {}
+        placements = []
+        if not pending:
+            return groups, placements
+        self.stats.rounds += 1 if any(job.clients for _, job, _ in pending) else 0
+        with span("plan", rows=sum(len(job.clients) for _, job, _ in pending)):
+            for i, job, plans in pending:
+                if not job.clients:
+                    # async drain-only tick (or a tick whose every flow
+                    # failed): no rows to place, the post phase still runs it
+                    placements.append((i, job, None, [], []))
+                    continue
+                mu = float(job.prox_mu)
+                gkey = (job.steps, mu > 0)
+                g = groups.setdefault(gkey, {"index": {}, "aindex": {}, "anchors": [],
+                                             "aidx": [], "rows": [], "mus": []})
+                pkey = prov.params_keys[i]
+                idxs, row_keys = [], []
+                for client, plan in zip(job.clients, plans):
+                    self.stats.fit_rows_total += 1
+                    if self.coalesce:
+                        rkey = (pkey, self.task.plan_digest(client, plan), job.steps, mu)
+                    else:
+                        rkey = ("row", next(prov.nonce))
+                    j = g["index"].get(rkey)
+                    if j is None:
+                        j = g["index"][rkey] = len(g["rows"])
+                        # anchors dedupe on params provenance (equal keys =>
+                        # bitwise-equal params); rows carry a gather index
+                        ai = g["aindex"].get(pkey)
+                        if ai is None:
+                            ai = g["aindex"][pkey] = len(g["anchors"])
+                            g["anchors"].append(self.servers[i].global_params)
+                        g["aidx"].append(ai)
+                        g["rows"].append((client, plan))
+                        g["mus"].append(mu)
+                    idxs.append(j)
+                    row_keys.append(prov.intern(rkey))
+                placements.append((i, job, gkey, idxs, row_keys))
+        return groups, placements
+
+    def dispatch(self, groups: Dict[tuple, dict]) -> None:
+        """One fused fit program per chunk of each group's rows; the
+        chunk outputs land in ``groups[gkey]["planes"]``."""
+        for (steps, use_prox), g in groups.items():
+            rows = g["rows"]
+            self.stats.fit_rows_unique += len(rows)
+            planes = []
+            for s in range(0, len(rows), self.max_plane_rows):
+                sub = slice(s, s + self.max_plane_rows)
+                # chunk-local anchor table: stack only the anchors this
+                # chunk's rows reference (O(unique anchors x params)
+                # transfer, not O(rows x params))
+                local: Dict[int, int] = {}
+                aidx_sub = [local.setdefault(a, len(local)) for a in g["aidx"][sub]]
+                anchors_sub = [g["anchors"][a] for a in local]
+                self.stats.anchor_rows_stacked += len(anchors_sub)
+                planes.append(self.task.fit_rows(
+                    anchors_sub, rows[sub], steps, g["mus"][sub], use_prox,
+                    anchor_idx=aidx_sub,
+                ))
+            g["planes"] = planes
+
+    def post(self, groups: Dict[tuple, dict], placements) -> None:
+        """Per point, in point order: gather its delivered rows from the
+        plane outputs and finish its round on its server. ``memo`` lets
+        points whose compression provenance coincides (same compressor,
+        same residual chain, same rows on the same client slots) share
+        ONE heavy compress pass this round."""
+        memo: Dict[tuple, Any] = {}
+        for i, job, gkey, idxs, row_keys in placements:
+            if idxs:
+                with span("gather_rows", rows=len(idxs),
+                          segments=_segments(idxs, self.max_plane_rows)):
+                    stacked, weights, per_metrics = _gather_rows(
+                        groups[gkey]["planes"], self.max_plane_rows, idxs
+                    )
+                self.stats.gather_dispatches += 1
+            else:  # async drain-only tick: no rows were placed
+                stacked, weights, per_metrics = None, [], []
+            self._finish_point(i, job, stacked, weights, per_metrics, row_keys, memo)
+        self.stats.compress_computed += len(memo)
+
+    def _finish_point(self, i: int, job: FitJob, stacked, weights, per_metrics,
+                      row_keys: List[int], memo: Dict[tuple, Any]) -> None:
+        """Compute point i's post-round provenance keys, finish the round
+        on its server, and keep the keys only if the server took the
+        update (a crash or quarantine leaves params and residuals at the
+        round boundary)."""
+        srv, prov = self.servers[i], self.prov
+        comp, cfg = srv.compressor, srv.config
+        prior = prov.params_keys[i], prov.res_keys[i]
+        pkey, reskey = prior
+        shared = comp_term = row_prov = None
+        # a compressor is provenance-shareable when its transform is a
+        # deterministic function of (delta, residual): fingerprinted and
+        # plane-capable, so finish_round takes the stacked path
+        comp_ok = comp.name == "none" or (
+            bool(comp.fingerprint) and comp.compress_plane is not None
+        )
+        if self.coalesce and comp_ok and srv.strategy.agg_fingerprint:
+            if comp.name != "none" and job.clients:
+                # residual-digest term: the decompressed deltas (and the
+                # post-round residual plane) are determined by (compressor,
+                # prior residual provenance, the rows' content, which
+                # client slots they land on)
+                slots = tuple(srv.client_slots(job.clients))
+                shared = (memo, (comp.fingerprint, reskey, tuple(row_keys), slots))
+                comp_term = ("comp", comp.fingerprint, reskey, slots)
+                reskey = prov.intern(("res", reskey, comp.fingerprint, tuple(row_keys), slots))
+            if cfg.async_mode:
+                # async provenance is event-granular: each dispatched row
+                # gets a token identifying its delta bitwise — (row
+                # content, compression applied at dispatch). The tokens
+                # ride the event queue; the params key only advances when
+                # a flush applies them (_Provenance.async_flush).
+                row_prov = tuple(prov.intern(("prov", k, comp_term)) for k in row_keys)
+            else:
+                pkey = prov.intern((
+                    "agg", pkey, srv.strategy.agg_fingerprint, tuple(row_keys),
+                    tuple(weights), job.rnd, bool(cfg.batched), comp_term,
+                ))
+        else:
+            reskey = prov.opaque()
+            if not cfg.async_mode:
+                pkey = prov.opaque()
+        if cfg.async_mode:
+            srv._plane_row_keys = row_prov  # None: events carry opaque prov
+        # staged before the call: the memoized eval inside finish_round
+        # keys on params_keys[i], and an async flush advances it there
+        prov.params_keys[i], prov.res_keys[i] = pkey, reskey
+        if not srv.finish_round(job, stacked, None, weights, per_metrics,
+                                shared_compress=shared):
+            prov.params_keys[i], prov.res_keys[i] = prior
+        elif shared is not None:
+            self.stats.compress_requested += 1
+
+
 def run_fl_grid(
     task: LocalTask,
     points: Sequence[GridPoint],
@@ -408,389 +730,13 @@ def run_fl_grid(
     """
     if transport not in ("per_point", "parity", "fused"):
         raise ValueError(f"unknown transport mode {transport!r}")
-    stats = GridStats()
-    nonce = itertools.count()
-    interned: Dict[Any, int] = {}
-
-    def intern(key) -> int:
-        v = interned.get(key)
-        if v is None:
-            v = len(interned)
-            interned[key] = v
-        return v
-
-    # params provenance per point: equal keys => bitwise-equal global
-    # params (same init, same aggregation chain over the same rows).
-    # res_keys mirrors it for the compression error-feedback plane: equal
-    # keys => bitwise-equal residual state (same compressor, same chain of
-    # (rows, delivering slots) updates from zeros).
-    params_keys: List[int] = []
-    res_keys: List[int] = []
-    eval_cache: Dict[Tuple[int, int], Dict[str, float]] = {}
-    servers: List[FederatedServer] = []
-
-    def make_eval(i: int):
-        def _eval(params, data):
-            stats.evals_requested += 1
-            key = (params_keys[i], id(data))
-            hit = eval_cache.get(key)
-            if hit is None:
-                hit = task.evaluate(params, data)
-                eval_cache[key] = hit
-                stats.evals_computed += 1
-            return dict(hit)  # finish_round annotates the dict in place
-
-        return _eval
-
-    for i, p in enumerate(points):
-        servers.append(
-            FederatedServer(
-                task,
-                p.clients,
-                p.strategy,
-                tcp=p.tcp,
-                chaos=p.chaos,
-                config=p.config,
-                compressor=p.compressor,
-                eval_data=eval_data,
-                eval_fn=make_eval(i),
-            )
-        )
-        params_keys.append(intern(("init", id(task), p.config.seed)))
-        res_keys.append(intern(("res0", servers[-1].compressor.fingerprint)))
-
-    def _async_prov_hook(i: int):
-        """Advance point i's params provenance at buffer-flush time.
-
-        finish_round calls this right after ``_async_tick`` and BEFORE the
-        memoized eval, so the eval cache keys on the post-flush
-        trajectory. No flush => params unchanged => the key stands (and
-        drain-only ticks keep coalescing with their pre-tick twins). A
-        flush whose events all carry provenance tokens digests to
-        ("agg-async", prior key, aggregation identity, the (token,
-        staleness, weight) event tuple, alpha, round) — two async points
-        flushing identical events over identical trajectories keep
-        bitwise-equal params and shared eval."""
-
-        def hook(srv: FederatedServer, rnd: int) -> None:
-            fl = srv._last_flush
-            if fl is None:
-                return
-            stats.async_flushes += 1
-            if fl["opaque"]:
-                params_keys[i] = intern(("opaque", next(nonce)))
-            else:
-                params_keys[i] = intern((
-                    "agg-async",
-                    params_keys[i],
-                    srv.strategy.agg_fingerprint,
-                    fl["events"],
-                    float(srv.config.staleness_alpha),
-                    rnd,
-                    bool(srv.config.batched),
-                ))
-
-        return hook
-
-    for i, srv in enumerate(servers):
-        if srv.config.async_mode:
-            srv._async_prov_hook = _async_prov_hook(i)
-
-    plane_ok = (
-        task.plan_fit is not None
-        and task.fit_rows is not None
-        and task.plan_digest is not None
+    sweep = _Sweep(
+        task, points, eval_data, coalesce=coalesce, max_plane_rows=max_plane_rows,
+        transport=transport, transport_seed=transport_seed,
     )
+    servers, stats, intern = sweep.servers, sweep.stats, sweep.prov.intern
+    params_keys, res_keys = sweep.prov.params_keys, sweep.prov.res_keys
     max_rounds = max((p.config.rounds for p in points), default=0)
-
-    hoist = transport in ("parity", "fused")
-
-    def _hoistable(srv: FederatedServer) -> bool:
-        # the hoist reproduces the BATCHED cohort draw discipline, and a
-        # point's selection stream only survives it under the split-rng
-        # contract; everything else keeps sampling inside begin_round.
-        # Parity mode is defined as bitwise per-point reproduction, and a
-        # device-backend point's per-point reference is the device plane
-        # keyed on its own (seed, stream, round) — a hoisted numpy pass
-        # cannot reproduce it, so such points stay on their own path.
-        if transport == "parity" and srv.config.transport_backend == "device":
-            return False
-        return srv.config.stochastic and srv.config.batched and srv.split_streams
-
-    def _round(rnd: int) -> None:
-        # --- per-point pre phase: selection on the point's own RNG stream;
-        # transport inline (per_point) or deferred to the shared plane ------
-        jobs = []  # (point_idx, FitJob)
-        waiting = []  # (point_idx, PendingRound) awaiting plane transport
-        for i, srv in enumerate(servers):
-            if srv.terminated or rnd >= srv.config.rounds:
-                continue
-            if hoist and _hoistable(srv):
-                pr = srv.select_cohort(rnd)
-                if pr is not None:
-                    if len(pr.cohort) == 0:
-                        # async drain-only tick: nothing to sample, the
-                        # plane never sees it — the tick still drains its
-                        # event queue through finish_round
-                        job = srv.finish_transport(
-                            pr,
-                            np.zeros(0, bool),
-                            np.zeros(0),
-                            np.zeros(0),
-                            np.zeros(0),
-                        )
-                        if job is not None:
-                            jobs.append((i, job))
-                    else:
-                        waiting.append((i, pr))
-                continue
-            job = srv.begin_round(rnd)
-            if job is not None:
-                jobs.append((i, job))
-
-        # --- transport plane: ONE stochastic sim_grid_round for the round --
-        if waiting:
-            n_rows = sum(len(pr.cohort) for _, pr in waiting)
-            with span("transport", rows=n_rows):
-                outcomes = _plane_transport(
-                    waiting, servers, transport, transport_seed, rnd, stats
-                )
-            stats.transport_rows += n_rows
-            for (i, pr), (succ, tt, rc, ba) in zip(waiting, outcomes):
-                job = servers[i].finish_transport(pr, succ, tt, rc, ba)
-                if job is not None:
-                    jobs.append((i, job))
-            jobs.sort(key=lambda ij: ij[0])  # point order, deterministic
-
-        pending = []  # (point_idx, FitJob, plans)
-        for i, job in jobs:
-            srv = servers[i]
-            if not (plane_ok and srv.config.batched):
-                # no plane path for this point/task: run it standalone
-                stacked, deltas, weights, per_metrics = srv.execute_fit(job)
-                params_keys[i] = intern(("opaque", next(nonce)))
-                res_keys[i] = intern(("opaque", next(nonce)))
-                srv.finish_round(job, stacked, deltas, weights, per_metrics)
-                continue
-            with span("plan", rows=len(job.clients)):
-                plans = task.plan_fit(job.clients, job.steps, srv.rng)
-            pending.append((i, job, plans))
-        if not pending:
-            return
-        stats.rounds += 1 if any(p[1].clients for p in pending) else 0
-
-        # --- row table: coalesce identical rows across points ---------------
-        with span("plan", rows=sum(len(job.clients) for _, job, _ in pending)):
-            # groups keyed by the plane program's static axes (steps, use_prox)
-            groups: Dict[tuple, dict] = {}
-            placements = []  # (point_idx, job, group_key, row idxs, row keys)
-            for i, job, plans in pending:
-                if not job.clients:
-                    # async drain-only tick (or a tick whose every flow
-                    # failed): no rows to place, the post phase still runs it
-                    placements.append((i, job, None, [], []))
-                    continue
-                mu = float(job.prox_mu)
-                gkey = (job.steps, mu > 0)
-                g = groups.setdefault(
-                    gkey,
-                    {"index": {}, "aindex": {}, "anchors": [], "aidx": [],
-                     "rows": [], "mus": []},
-                )
-                idxs, row_keys = [], []
-                for client, plan in zip(job.clients, plans):
-                    stats.fit_rows_total += 1
-                    if coalesce:
-                        rkey = (
-                            params_keys[i],
-                            task.plan_digest(client, plan),
-                            job.steps,
-                            mu,
-                        )
-                    else:
-                        rkey = ("row", next(nonce))
-                    j = g["index"].get(rkey)
-                    if j is None:
-                        j = len(g["rows"])
-                        g["index"][rkey] = j
-                        # anchors dedupe on params provenance (equal keys =>
-                        # bitwise-equal params); rows carry a gather index
-                        ai = g["aindex"].get(params_keys[i])
-                        if ai is None:
-                            ai = len(g["anchors"])
-                            g["aindex"][params_keys[i]] = ai
-                            g["anchors"].append(servers[i].global_params)
-                        g["aidx"].append(ai)
-                        g["rows"].append((client, plan))
-                        g["mus"].append(mu)
-                    idxs.append(j)
-                    row_keys.append(intern(rkey))
-                placements.append((i, job, gkey, idxs, row_keys))
-
-        # --- plane dispatch: one fused program per group chunk --------------
-        for gkey, g in groups.items():
-            steps, use_prox = gkey
-            rows = g["rows"]
-            stats.fit_rows_unique += len(rows)
-            planes = []
-            for s in range(0, len(rows), max_plane_rows):
-                sub = slice(s, s + max_plane_rows)
-                # chunk-local anchor table: stack only the anchors this
-                # chunk's rows reference (O(unique anchors x params)
-                # transfer, not O(rows x params))
-                local: Dict[int, int] = {}
-                anchors_sub: List[Any] = []
-                aidx_sub: List[int] = []
-                for a in g["aidx"][sub]:
-                    la = local.get(a)
-                    if la is None:
-                        la = len(anchors_sub)
-                        local[a] = la
-                        anchors_sub.append(g["anchors"][a])
-                    aidx_sub.append(la)
-                stats.anchor_rows_stacked += len(anchors_sub)
-                plane, n_ex, mets = task.fit_rows(
-                    anchors_sub, rows[sub], steps, g["mus"][sub], use_prox,
-                    anchor_idx=aidx_sub,
-                )
-                planes.append((plane, n_ex, mets))
-            g["planes"] = planes
-
-        # --- per-point post phase: scatter, aggregate, advance provenance ---
-        # round-scoped memo for the heavy compress_rows program: points
-        # whose compression provenance coincides (same compressor, same
-        # residual chain, same rows on the same client slots) share ONE
-        # top-k/quantize pass; each point still scatters its own residual
-        # plane (cheap, donated)
-        comp_memo: Dict[tuple, Any] = {}
-        for i, job, gkey, idxs, row_keys in placements:
-            srv = servers[i]
-            if idxs:
-                with span("gather_rows", rows=len(idxs),
-                          segments=_segments(idxs, max_plane_rows)):
-                    stacked, weights, per_metrics = _gather_rows(
-                        groups[gkey]["planes"], max_plane_rows, idxs
-                    )
-                stats.gather_dispatches += 1
-            else:  # async drain-only tick: no rows were placed
-                stacked, weights, per_metrics = None, [], []
-            # fault domain first, BEFORE the shared compression pass can
-            # mutate this point's residual plane or provenance: a server
-            # crash inside the round span loses the round (params and
-            # residuals stay at the round boundary — params_keys/res_keys
-            # unchanged); a quarantine trigger retires only this row of
-            # the sweep, leaving every other point's dispatch untouched
-            # (row independence: rows never reduce across points). Async
-            # ticks use the deadline-horizon crash window — every event a
-            # tick can land falls inside it (see finish_round) — and the
-            # async abort also voids the event queue and buffer.
-            if srv.config.async_mode:
-                crash = srv.chaos.server_restart_in(
-                    job.record.t_start,
-                    job.record.t_start + srv.config.round_deadline,
-                )
-                if crash is not None:
-                    srv._abort_tick_server_restart(job.record, crash)
-                    continue
-                if srv.config.quarantine and job.clients:
-                    cause = srv._divergence_cause(stacked, None, per_metrics)
-                    if cause is not None:
-                        srv._quarantine_round(job, cause)
-                        continue
-            else:
-                round_time = min(max(job.arrivals), srv.config.round_deadline)
-                crash = srv.chaos.server_restart_in(
-                    job.record.t_start, job.record.t_start + round_time
-                )
-                if crash is not None:
-                    srv._abort_round_server_restart(job.record, crash)
-                    continue
-                if srv.config.quarantine:
-                    cause = srv._divergence_cause(stacked, None, per_metrics)
-                    if cause is not None:
-                        srv._quarantine_round(job, cause)
-                        continue
-            comp = srv.compressor
-            # a compressor is provenance-shareable when its transform is a
-            # deterministic function of (delta, residual) — fingerprinted
-            # and plane-capable, so finish_round takes the stacked path
-            comp_ok = comp.name == "none" or (
-                bool(comp.fingerprint) and comp.compress_plane is not None
-            )
-            sharable = (
-                coalesce and comp_ok and bool(srv.strategy.agg_fingerprint)
-            )
-            precompressed = False
-            if sharable:
-                comp_term = None
-                if comp.name != "none" and job.clients:
-                    # residual-digest term: the decompressed deltas (and
-                    # the post-round residual plane) are determined by
-                    # (compressor, prior residual provenance, the rows'
-                    # content, which client slots they land on)
-                    slots = tuple(srv.client_slots(job.clients))
-                    ckey = (
-                        comp.fingerprint, res_keys[i], tuple(row_keys), slots
-                    )
-                    stats.compress_requested += 1
-                    plane_fn = comp.compress_plane
-                    plane = srv._ensure_residual_plane()
-                    # provenance (ckey) is keyed on SLOTS — stable client
-                    # identities — while the jitted gather/scatter take
-                    # physical buffer rows (identity under dense storage,
-                    # compacted under sparse; values are slot-determined
-                    # either way, so memo hits stay bitwise-safe)
-                    rows_j = jnp.asarray(
-                        plane.rows_for(np.asarray(slots, np.int32)), jnp.int32
-                    )
-                    hit = comp_memo.get(ckey)
-                    if hit is None:
-                        rows = plane_fn.gather_rows(plane.buffer, rows_j)
-                        hit = plane_fn.compress_rows(stacked, rows)
-                        comp_memo[ckey] = hit
-                        stats.compress_computed += 1
-                    x2_t, deq_t = hit
-                    plane.buffer = plane_fn.scatter_rows(
-                        x2_t, deq_t, plane.buffer, rows_j
-                    )
-                    stacked = plane_fn.finalize(stacked, deq_t)
-                    precompressed = True
-                    comp_term = ("comp", comp.fingerprint, res_keys[i], slots)
-                    res_keys[i] = intern(
-                        ("res", res_keys[i], comp.fingerprint,
-                         tuple(row_keys), slots)
-                    )
-                if srv.config.async_mode:
-                    # async provenance is event-granular: each dispatched
-                    # row gets a token identifying its delta bitwise —
-                    # (row content, compression applied at dispatch). The
-                    # tokens ride the event queue; the params key only
-                    # advances when a flush applies them (the prov hook).
-                    srv._plane_row_keys = tuple(
-                        intern(("prov", rk, comp_term)) for rk in row_keys
-                    )
-                else:
-                    digest = (
-                        "agg",
-                        params_keys[i],
-                        srv.strategy.agg_fingerprint,
-                        tuple(row_keys),
-                        tuple(weights),
-                        rnd,
-                        bool(srv.config.batched),
-                        comp_term,
-                    )
-                    params_keys[i] = intern(digest)
-            else:
-                if srv.config.async_mode:
-                    srv._plane_row_keys = None  # events carry opaque prov
-                else:
-                    params_keys[i] = intern(("opaque", next(nonce)))
-                res_keys[i] = intern(("opaque", next(nonce)))
-            srv.finish_round(
-                job, stacked, None, weights, per_metrics,
-                precompressed=precompressed, fault_checked=True,
-            )
 
     # --- crash consistency: round-boundary checkpoint save/restore --------
     fingerprint = {
@@ -904,7 +850,7 @@ def run_fl_grid(
     )
     for rnd in range(start_round, end_round):
         with span("round", round=rnd):
-            _round(rnd)
+            sweep.round(rnd)
         if mgr is not None and (rnd + 1) % checkpoint_every == 0:
             _save_checkpoint(mgr, rnd + 1)
             stats.checkpoints_saved += 1

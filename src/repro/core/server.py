@@ -54,7 +54,6 @@ from repro.transport.des import (
     delivery_events,
     sim_client_round,
     sim_cohort_round,
-    sim_grid_round,
 )
 from repro.transport.params import RetryPolicy
 from repro.utils import tree_stack, tree_unstack
@@ -221,15 +220,6 @@ class ServerConfig:
     # distributions but with a different draw order, so the two engines
     # are distribution-equivalent, not draw-for-draw identical.
     batched: bool = False
-    # transport engine selector (stochastic mode only). "default" keeps
-    # sim_cohort_round's draw discipline; "fused_transport" routes the
-    # cohort through sim_grid_round's shared-rng plane (and implies
-    # rng_streams="split"). Both engines now bill ASYMMETRIC payloads —
-    # uploads carry the compressor's exact wire size, downloads the full
-    # model (LocalTask.update_bytes) — so the flag's remaining delta is
-    # the draw order, and it is the entry point the grid driver extends
-    # to an [S*C]-row plane across sweep points (run_fl_grid transport=).
-    engine: str = "default"
     # RNG stream discipline. "single" (the seed-compatible historical
     # stream): ONE generator drives cohort selection, transport sampling,
     # and batch plans in interleaved consumption order — bitwise identical
@@ -240,7 +230,6 @@ class ServerConfig:
     # a point's selection sequence is bitwise invariant to which engine
     # sampled transport (per-point loop, per-scenario parity plane, or the
     # grid's shared fused plane) and to how many draws transport consumed.
-    # engine="fused_transport" implies "split".
     rng_streams: str = "single"
     # Where stochastic transport is SAMPLED. "host" keeps the numpy
     # Monte-Carlo plane (the parity oracle). "device" routes the cohort
@@ -296,8 +285,6 @@ class ServerConfig:
             raise ValueError(f"unknown state_plane {self.state_plane!r}")
         # typos here would silently select the legacy stream discipline
         # and silently exclude points from the grid's transport hoist
-        if self.engine not in ("default", "fused_transport"):
-            raise ValueError(f"unknown engine {self.engine!r}")
         if self.rng_streams not in ("single", "split"):
             raise ValueError(f"unknown rng_streams {self.rng_streams!r}")
         if self.transport_backend not in ("host", "device"):
@@ -457,7 +444,6 @@ class FederatedServer:
         two derived per-round streams (see ServerConfig.rng_streams)."""
         return (
             self.config.rng_streams == "split"
-            or self.config.engine == "fused_transport"
             or self.config.transport_backend == "device"
         )
 
@@ -565,30 +551,6 @@ class FederatedServer:
                     np.asarray(t, float)[0],
                     np.asarray(rc, float)[0],
                     np.asarray(ba, float)[0],
-                )
-            if cfg.engine == "fused_transport":
-                # opt-in shared-rng plane (sim_grid_round fused mode): the
-                # S=1 special case of the grid driver's (S, C) transport
-                # plane, draw-for-draw identical to the default path.
-                out = sim_grid_round(
-                    self.tcp,
-                    [links],
-                    update_bytes=np.full(
-                        (1, len(cohort)), pending.upload_bytes, np.int64
-                    ),
-                    download_bytes=np.full(
-                        (1, len(cohort)), pending.download_bytes, np.int64
-                    ),
-                    local_train_times=local_times[None],
-                    rng=rng,
-                    connected=connected[None],
-                    retry=self._effective_retry(),
-                )
-                return (
-                    out.success[0],
-                    out.time[0],
-                    out.reconnects[0].astype(float),
-                    out.bytes_acked[0].astype(float),
                 )
             out = sim_cohort_round(
                 self.tcp,
@@ -1067,14 +1029,17 @@ class FederatedServer:
 
     def finish_round(
         self, job: FitJob, stacked, deltas, weights, per_metrics,
-        precompressed: bool = False, fault_checked: bool = False,
-    ) -> None:
-        """Compression, bookkeeping, aggregation, clock advance, eval.
+        shared_compress: Optional[tuple] = None,
+    ) -> bool:
+        """Fault domain, compression, bookkeeping, aggregation, clock
+        advance, eval. Returns whether the round's update was taken:
+        False when a server crash or a quarantine rejected it.
 
-        ``precompressed=True`` means the caller (the grid engine) already
-        ran plane compression — possibly shared across sweep points with
-        equal compression provenance — and ``stacked`` holds decompressed
-        deltas with this server's residual plane already advanced.
+        ``shared_compress=(memo, key)`` lets sweep points share one heavy
+        ``compress_rows`` pass: ``key`` is this point's compression
+        provenance (equal keys imply bitwise-equal compressed rows) and
+        ``memo`` a dict scoped to the grid round. Each point still
+        scatters its own residual plane.
 
         Byte accounting follows the asymmetric payload convention:
         ``job.payload_bytes`` (credited to ``client.bytes_sent``) is the
@@ -1083,85 +1048,83 @@ class FederatedServer:
         Consumes no RNG: everything stochastic about a round happens in
         ``begin_round``/``execute_fit``."""
         with span("finish_round", rows=len(job.clients)):
-            self._finish_round(job, stacked, deltas, weights, per_metrics,
-                               precompressed, fault_checked)
+            return self._finish_round(
+                job, stacked, deltas, weights, per_metrics, shared_compress
+            )
+
+    def _reject_faulted_round(self, job: FitJob, stacked, deltas, per_metrics) -> bool:
+        """The round's fault domain, checked before any state mutates.
+        Returns True when the round was rejected (and recorded).
+
+        A server crash inside the round span loses the round outright; a
+        quarantine trigger (non-finite loss/delta) rejects it before
+        compression, so the residual plane never ingests poison. The sync
+        window closes at the round's last arrival (capped at the
+        deadline). The async tick window is the full deadline horizon:
+        every event a tick can land falls in (t_start, t_start +
+        round_deadline] — fresh dispatches land within the deadline by
+        construction, and queued events were dispatched at earlier ticks
+        — so a crash inside it voids the tick, losing every in-flight
+        update and the buffer (a crash drops server state)."""
+        cfg = self.config
+        record = job.record
+        if cfg.async_mode:
+            window = cfg.round_deadline
+        else:
+            window = min(max(job.arrivals), cfg.round_deadline)
+        crash = self.chaos.server_restart_in(record.t_start, record.t_start + window)
+        if crash is not None:
+            if cfg.async_mode:
+                self._abort_tick_server_restart(record, crash)
+            else:
+                self._abort_round_server_restart(record, crash)
+            return True
+        if cfg.quarantine and job.clients:
+            cause = self._divergence_cause(stacked, deltas, per_metrics)
+            if cause is not None:
+                self._quarantine_round(job, cause)
+                return True
+        return False
+
+    def _compress(self, dclients, stacked, deltas, shared_compress):
+        """Compress the round's deltas; returns (stacked, deltas), the
+        decompressed updates. The plane path keeps the whole cohort
+        stacked — error-feedback residuals live in a StatePlane and the
+        compressor's donated jit gathers the delivering rows, compresses,
+        and scatters new residuals back (bitwise identical to the
+        per-client loop); ``shared_compress=(memo, key)`` is passed on so
+        the plane runs its heavy pass once per key. Compressors without a
+        plane twin (stateful randk) or unstacked deltas take the
+        per-client loop."""
+        plane_fn = self.compressor.compress_plane
+        if stacked is not None and plane_fn is not None:
+            plane = self._ensure_residual_plane()
+            # physical buffer rows for the cohort's slots (identity under
+            # dense storage; compacted rows under sparse)
+            rows = plane.rows_for(np.asarray(self.client_slots(dclients), np.int32))
+            memo, key = shared_compress or (None, None)
+            stacked, plane.buffer = plane_fn(stacked, plane.buffer, rows, memo, key)
+            return stacked, deltas
+        if stacked is not None:
+            deltas = tree_unstack(stacked)
+        compressed = []
+        for client, delta in zip(dclients, deltas):
+            payload, client.residual = self.compressor.compress(delta, client.residual)
+            compressed.append(self.compressor.decompress(payload))
+        return None, compressed
 
     def _finish_round(
-        self, job: FitJob, stacked, deltas, weights, per_metrics,
-        precompressed: bool, fault_checked: bool,
-    ) -> None:
+        self, job: FitJob, stacked, deltas, weights, per_metrics, shared_compress,
+    ) -> bool:
         cfg = self.config
         rnd = job.rnd
         record = job.record
         dclients = job.clients
-        arrivals = job.arrivals
-
-        # fault domain, checked before any state mutates: a server crash
-        # inside the round span loses the round outright; a quarantine
-        # trigger (non-finite loss/delta) rejects it before compression so
-        # the residual plane never ingests poison. ``fault_checked=True``
-        # means the caller (the grid driver, which must check before its
-        # SHARED compression pass) already ran both checks. The async tick
-        # fault window is the full deadline horizon: every event the tick
-        # can land falls in (t_start, t_start + round_deadline] — fresh
-        # dispatches land within the deadline by construction, and queued
-        # events were dispatched at earlier (<= t_start) ticks — so a
-        # server_restart inside that window voids the tick, losing every
-        # in-flight update and the buffer (crash drops server state).
-        if cfg.async_mode:
-            if not fault_checked:
-                crash = self.chaos.server_restart_in(
-                    record.t_start, record.t_start + cfg.round_deadline
-                )
-                if crash is not None:
-                    self._abort_tick_server_restart(record, crash)
-                    return
-                if cfg.quarantine and dclients:
-                    cause = self._divergence_cause(stacked, deltas, per_metrics)
-                    if cause is not None:
-                        self._quarantine_round(job, cause)
-                        return
-        else:
-            round_time = min(max(arrivals), cfg.round_deadline)
-            if not fault_checked:
-                crash = self.chaos.server_restart_in(
-                    record.t_start, record.t_start + round_time
-                )
-                if crash is not None:
-                    self._abort_round_server_restart(record, crash)
-                    return
-                if cfg.quarantine:
-                    cause = self._divergence_cause(stacked, deltas, per_metrics)
-                    if cause is not None:
-                        self._quarantine_round(job, cause)
-                        return
-
-        # compression: the plane path keeps the whole cohort stacked —
-        # error-feedback residuals live in a [N_clients, ...] device plane
-        # and the compressor's donated jit gathers the delivering rows,
-        # compresses, and scatters new residuals back (bitwise identical
-        # to the per-client loop). Compressors without a plane twin
-        # (stateful randk) or unstacked deltas fall back to the loop.
-        if self.compressor.name != "none" and not precompressed:
-            plane_fn = self.compressor.compress_plane
-            if stacked is not None and plane_fn is not None:
-                plane = self._ensure_residual_plane()
-                slots = np.asarray(self.client_slots(dclients), np.int32)
-                # physical buffer rows for the cohort's slots (identity
-                # under dense storage; compacted rows under sparse)
-                rows = plane.rows_for(slots)
-                stacked, plane.buffer = plane_fn(stacked, plane.buffer, rows)
-            else:
-                if stacked is not None:
-                    deltas = tree_unstack(stacked)
-                    stacked = None
-                compressed = []
-                for client, delta in zip(dclients, deltas):
-                    payload, client.residual = self.compressor.compress(
-                        delta, client.residual
-                    )
-                    compressed.append(self.compressor.decompress(payload))
-                deltas = compressed
+        if self._reject_faulted_round(job, stacked, deltas, per_metrics):
+            self._plane_row_keys = None  # the tick's rows never enter the queue
+            return False
+        if self.compressor.name != "none" and dclients:
+            stacked, deltas = self._compress(dclients, stacked, deltas, shared_compress)
 
         for client, m in zip(dclients, per_metrics):
             client.rounds_participated += 1
@@ -1181,7 +1144,7 @@ class FederatedServer:
                 m["round"] = rnd
                 m["t"] = self.sim_time
                 self.history.eval_metrics.append(m)
-            return
+            return True
         if cfg.batched:
             # stacked-delta fast path: kernel-backed reduction (falls
             # back to the list path inside aggregate_stacked when the
@@ -1196,7 +1159,7 @@ class FederatedServer:
                 self.global_params, deltas, weights, rnd
             )
 
-        self.sim_time += round_time
+        self.sim_time += min(max(job.arrivals), cfg.round_deadline)
         record.t_end = self.sim_time
         self.history.rounds.append(record)
 
@@ -1205,6 +1168,7 @@ class FederatedServer:
             m["round"] = rnd
             m["t"] = self.sim_time
             self.history.eval_metrics.append(m)
+        return True
 
     # ------------------------------------------------------------------
     # event-driven async engine (config.async_mode)

@@ -11,7 +11,7 @@ inside a jitted function, where they would fire only while tracing.
 Open a run inside ``jax.profiler.trace(logdir)`` and read these in Perfetto
 (metadata in brackets):
 
-- ``fl.round`` [round]: one engine round: the body of ``run_fl_grid``'s round, one iteration of ``FederatedServer.run``.
+- ``fl.round`` [round]: one engine round: one grid round (``_Sweep.round`` in ``run_fl_grid``), one iteration of ``FederatedServer.run``.
 - ``fl.select`` [cohort]: ``FederatedServer.select_cohort``: liveness, cohort draw, links.
 - ``fl.shard_build`` [examples]: ``Population.client`` building one drawn client's shard.
 - ``fl.transport`` [rows]: transport sampling: ``FederatedServer.run_transport``, the grid's shared transport plane.
@@ -22,9 +22,9 @@ Open a run inside ``jax.profiler.trace(logdir)`` and read these in Perfetto
 - ``fl.fit.anchors`` [anchors]: the host build of one block's anchor table (its distinct anchors), row index and prox arrays.
 - ``fl.fit.dispatch`` [rows, steps]: the fit program's call for one block.
 - ``fl.gather_rows`` [rows, segments]: the grid's gather of one point's rows from the fit planes, one jitted program each (``GridStats.gather_dispatches`` counts them); ``segments``: runs of rows from one plane chunk, above 1 where the rows span chunks.
-- ``fl.divergence``: ``FederatedServer._divergence_cause``, the quarantine check.
+- ``fl.divergence``: ``FederatedServer._divergence_cause``, the quarantine check, inside ``fl.finish_round``.
 - ``fl.aggregate`` [rows]: ``Strategy.aggregate_stacked``.
-- ``fl.finish_round`` [rows]: ``FederatedServer.finish_round``: bookkeeping, aggregation, eval.
+- ``fl.finish_round`` [rows]: ``FederatedServer.finish_round``: fault domain, compression, bookkeeping, aggregation, eval.
 - ``fl.evaluate`` [examples]: the task's ``evaluate``.
 - ``fl.sync.<site>`` [bytes]: ``to_host``, the host waiting on the device; sites ``fit_metrics``, ``transport``, ``divergence``, ``eval``.
 """

@@ -2,6 +2,10 @@
 provenance coalescing, bucketed plane dispatch, chunked unrolling, and the
 (S, C) transport grid with sparse traces."""
 
+import dataclasses
+import gc
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +36,6 @@ EVAL = synthetic_mnist(300, seed=77)
 def _point(
     *, tcp=DEFAULT, link=LAB, chaos=None, strategy=None, min_fit=0.5, rounds=3,
     seed=0, local_steps=2, stochastic=False, batched=True, rng_streams="single",
-    engine="default",
 ):
     clients = [EdgeClient(i, dataset=s) for i, s in enumerate(SHARDS)]
     return GridPoint(
@@ -42,7 +45,7 @@ def _point(
         chaos or ChaosSchedule(link),
         ServerConfig(
             rounds=rounds, local_steps=local_steps, seed=seed, batched=batched,
-            stochastic=stochastic, rng_streams=rng_streams, engine=engine,
+            stochastic=stochastic, rng_streams=rng_streams,
         ),
     )
 
@@ -161,6 +164,25 @@ def test_grid_coalescing_off_still_exact():
     ref = _run_per_point(_point(**kwargs[0])).summary()
     for hist in res.histories:
         assert _summaries_exactly_equal(ref, hist.summary())
+
+
+def test_finished_grid_frees_its_servers_without_the_cycle_collector():
+    """Nothing the grid hangs on its servers (the memoized eval, the async
+    flush hook) refers back to them: once a grid's result is dropped,
+    reference counting alone frees every server and its device params,
+    so back-to-back sweeps do not pile up params for the cycle
+    collector."""
+    sync = _point(rounds=1)
+    asy = _point(rounds=1)
+    asy.config = dataclasses.replace(asy.config, async_mode=True)
+    gc.disable()
+    try:
+        res = run_fl_grid(TASK, [sync, asy], eval_data=EVAL)
+        refs = [weakref.ref(s) for s in res.servers]
+        del res
+        assert all(r() is None for r in refs)
+    finally:
+        gc.enable()
 
 
 # ---------------------------------------------------------------------------
@@ -529,13 +551,13 @@ def test_selection_stream_regression_vs_pre_split_engine(stochastic):
 def test_split_streams_selection_invariant_across_transport_engines():
     """rng_streams="split": the per-round derived cohort stream makes the
     selection sequence bitwise identical no matter which engine samples
-    transport — per-point default, per-point fused_transport (S=1 plane),
-    grid parity plane, or the grid's shared-rng fused plane."""
+    transport — per-point batched cohort, per-point sequential per-client
+    loop, grid parity plane, or the grid's shared-rng fused plane."""
     base = dict(stochastic=True, rng_streams="split", link=LAB.replace(loss=0.05))
     ref = _selected_ids(_run_per_point(_point(**base)))
     assert ref  # non-degenerate: rounds actually ran
 
-    alt = _selected_ids(_run_per_point(_point(**base, engine="fused_transport")))
+    alt = _selected_ids(_run_per_point(_point(**base, batched=False)))
     assert alt == ref
 
     for mode in ("parity", "fused"):

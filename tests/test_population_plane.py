@@ -232,8 +232,10 @@ def test_state_plane_sharding_hook():
 ENGINES = {
     "sequential": dict(batched=False),
     "batched": dict(batched=True),
+    # the split-stream stochastic cohort engine, under its older label
+    # so the parametrized ids stay stable
     "fused_transport": dict(
-        batched=True, stochastic=True, engine="fused_transport"
+        batched=True, stochastic=True, rng_streams="split"
     ),
 }
 

@@ -20,7 +20,7 @@ from repro.chaos import (
     server_restart,
 )
 from repro.checkpoint.store import CheckpointManager, load_tree, save_tree
-from repro.compress import randk_compressor, topk_compressor
+from repro.compress import get_compressor, randk_compressor, topk_compressor
 from repro.core import (
     EdgeClient,
     FederatedServer,
@@ -322,19 +322,23 @@ def _poisoned_shards():
     return [dataclasses.replace(s, images=images)] * len(SHARDS)
 
 
-def test_quarantine_isolates_poisoned_point():
+@pytest.mark.parametrize("comp", [None, "int8"])
+def test_quarantine_isolates_poisoned_point(comp):
     """A NaN-poisoned grid point is retired (status "diverged" + cause)
     while every OTHER point's history stays bitwise identical to a run
     without the poisoned point — the failed row never reaches shared
-    compression or aggregation state."""
+    compression or aggregation state. With int8 error feedback the
+    healthy points share one compression pass a round, and the poisoned
+    point never requests one."""
+    def mk(**kw):
+        return _point(comp=comp and get_compressor(comp), **kw)
+
     links = [LAB, LAB.replace(delay=0.3), LAB.replace(delay=1.0)]
-    ref = run_fl_grid(
-        TASK, [_point(link=l) for l in links], eval_data=EVAL
-    )
+    ref = run_fl_grid(TASK, [mk(link=l) for l in links], eval_data=EVAL)
     got = run_fl_grid(
         TASK,
-        [_point(link=links[0]), _point(_poisoned_shards()),
-         _point(link=links[1]), _point(link=links[2])],
+        [mk(link=links[0]), mk(shards=_poisoned_shards()),
+         mk(link=links[1]), mk(link=links[2])],
         eval_data=EVAL,
     )
     bad = got.histories[1]
@@ -344,6 +348,17 @@ def test_quarantine_isolates_poisoned_point():
     assert got.stats.quarantined == 1
     healthy = [got.histories[0], got.histories[2], got.histories[3]]
     _assert_histories_identical(ref.histories, healthy)
+    for a, b in zip(ref.servers, [got.servers[0], *got.servers[2:]]):
+        assert all(
+            np.array_equal(x, y)
+            for x, y in zip(jax.tree.leaves(a.global_params),
+                            jax.tree.leaves(b.global_params))
+        )
+    assert (got.stats.compress_requested, got.stats.compress_computed) == (
+        ref.stats.compress_requested, ref.stats.compress_computed
+    )
+    if comp is not None:
+        assert got.stats.compress_computed < got.stats.compress_requested
 
 
 def test_quarantine_reports_instead_of_raising():
@@ -405,12 +420,92 @@ def test_server_restart_loses_round_and_disconnects():
     assert later and not any(r.failed_round for r in later)
 
 
-def test_server_restart_in_grid_counts_and_isolates():
-    chaos = ChaosSchedule(LAB).add(server_restart(3.0, downtime=50.0))
-    res = run_fl_grid(TASK, [_point(chaos=chaos), _point()], eval_data=EVAL)
+@pytest.mark.parametrize("async_mode", [False, True])
+def test_server_restart_in_grid_counts_and_isolates(async_mode):
+    """A crash loses exactly one round (sync) or tick (async) of the
+    point it hits, matching that point's standalone run, and leaves the
+    other point untouched."""
+    kw = dict(rounds=4, async_mode=async_mode)
+    # the crash lands in the first round, so later rounds show whether the
+    # lost round's update leaked into the point's provenance keys
+    chaos = ChaosSchedule(LAB).add(server_restart(0.5, downtime=50.0))
+    res = run_fl_grid(
+        TASK, [_point(chaos=chaos, **kw), _point(**kw)], eval_data=EVAL
+    )
     assert res.stats.server_restarts == 1
     assert any(r.cause == "server_restart" for r in res.histories[0].rounds)
     assert not any(r.failed_round for r in res.histories[1].rounds)
+    _assert_histories_identical(
+        [_run_per_point(_point(chaos=chaos, **kw)), _run_per_point(_point(**kw))],
+        res.histories,
+    )
+
+
+def test_divergence_check_runs_once_per_post_phase(monkeypatch):
+    """Grid and per-point runs alike run the quarantine scan exactly once
+    per point-round, inside that point's finish_round, and never for a
+    round that failed before it (the 8 s point fails every round on
+    quorum)."""
+    finish, scan = FederatedServer.finish_round, FederatedServer._divergence_cause
+    posts, scans = [], []
+
+    def finish_round(srv, job, *a, **kw):
+        posts.append((id(srv), job.rnd))
+        return finish(srv, job, *a, **kw)
+
+    def divergence_cause(srv, *a):
+        scans.append((id(srv), posts[-1][1] if posts else None))
+        return scan(srv, *a)
+
+    monkeypatch.setattr(FederatedServer, "finish_round", finish_round)
+    monkeypatch.setattr(FederatedServer, "_divergence_cause", divergence_cause)
+    links = [LAB, LAB.replace(delay=8.0), LAB.replace(loss=0.15)]
+    res = run_fl_grid(TASK, [_point(link=l) for l in links], eval_data=EVAL)
+    p = _point()
+    srv = FederatedServer(
+        TASK, p.clients, p.strategy, tcp=p.tcp, chaos=p.chaos,
+        config=p.config, eval_data=EVAL,
+    )
+    srv.run()
+    assert scans == posts
+    for s in [*res.servers, srv]:
+        mine = [k for k in posts if k[0] == id(s)]
+        assert len(mine) == len(set(mine))
+        assert len(mine) == sum(not r.failed_round for r in s.history.rounds)
+    assert not any(k[0] == id(res.servers[1]) for k in posts)
+
+
+@pytest.mark.parametrize("async_mode", [False, True])
+@pytest.mark.parametrize("fault", ["none", "server_restart", "quarantine"])
+def test_finish_round_reports_whether_the_update_was_taken(async_mode, fault):
+    """finish_round returns False when a server_restart inside the round's
+    window or a quarantine trigger rejects the round — params stay at the
+    round boundary — and True otherwise, in sync rounds and async ticks."""
+    chaos = ChaosSchedule(LAB)
+    if fault == "server_restart":
+        chaos = chaos.add(server_restart(0.5, downtime=10.0))
+    shards = _poisoned_shards() if fault == "quarantine" else SHARDS
+    p = _point(shards, chaos=chaos, rounds=1, async_mode=async_mode)
+    srv = FederatedServer(
+        TASK, p.clients, p.strategy, tcp=p.tcp, chaos=p.chaos,
+        config=p.config, eval_data=EVAL,
+    )
+    before = jax.tree.map(np.asarray, srv.global_params)
+    job = srv.begin_round(0)
+    assert job is not None and job.clients
+    taken = srv.finish_round(job, *srv.execute_fit(job))
+    assert taken == (fault == "none")
+    unchanged = all(
+        np.array_equal(a, np.asarray(b))
+        for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(srv.global_params))
+    )
+    assert unchanged != taken
+    record = srv.history.rounds[-1]
+    assert record.failed_round != taken
+    if fault == "server_restart":
+        assert record.cause == "server_restart"
+    if fault == "quarantine":
+        assert srv.history.status == "diverged"
 
 
 def test_server_restart_in_window_resolution():
